@@ -432,6 +432,8 @@ def main() -> None:
     if "--smoke" in sys.argv[1:]:
         smoke()
         return
+    from repro import compile_cache
+    compile_cache.enable()
     nightly = "--nightly" in sys.argv[1:]
     if nightly:
         # full-timing mode: no reduced sweep, no gate opt-out, 3x reps

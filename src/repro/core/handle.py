@@ -39,12 +39,15 @@ Backend capability registry
 name           device  wide keys   min batch  notes
 =============  ======  ==========  =========  =====================
 fused          yes     yes (hi/lo  512        single-dispatch path:
-                       f32 pair)              fused Pallas kernel on
-                                              TPU, minimal-op fused
-                                              XLA graph elsewhere
-pallas         yes     no          512        LEGACY multi-op TPU
+                       f32 pair)              the minimal-op fused
+                                              XLA graph on every
+                                              platform (the fused
+                                              Pallas kernel only via
+                                              an explicit engine
+                                              ``fused_impl``)
+pallas         yes     no          512        LEGACY multi-op Pallas
                                               kernel (debug/ref;
-                                              interpret=True on CPU)
+                                              interpreted off-TPU)
 xla-windowed   yes     yes (hi/lo  512        legacy multi-op
                        f32 pair)              windowed bisect/rank
                                               (debug/reference)
@@ -56,7 +59,8 @@ everything else to ``fused`` — the single-dispatch path serves narrow
 AND wide (hi/lo pair) keys on every platform, so it owns the whole
 device regime including the small/medium batches the legacy multi-op
 paths used to lose to the oracle.  ``pallas`` / ``xla-windowed`` remain
-explicitly requestable as debug/reference stages.  Explicitly
+explicitly requestable as debug/reference stages; neither is compiled
+for the TPU by any default path.  Explicitly
 requesting a backend that cannot serve the index (e.g. the legacy
 ``pallas`` with >2^24 composite keys) raises with the capability that
 failed; keys aliasing beyond pair exactness (~2^48) refuse every device
@@ -95,15 +99,6 @@ class BackendSpec:
     wide_keys: bool         # exact beyond f32 (2^24) key magnitudes
     min_batch: int          # below this the backend loses to the host
     engine_backend: Optional[str]  # kernels.QueryEngine backend name
-
-    def available(self) -> bool:
-        if not self.device:
-            return True
-        import jax
-        if self.name == "pallas":
-            # auto-pick only on TPU; explicit requests run interpreted
-            return jax.default_backend() == "tpu"
-        return True
 
 
 BACKENDS: Dict[str, BackendSpec] = {
@@ -148,14 +143,15 @@ class Index:
     min_device_batch: int = 512
     # single-dispatch device-resident ingest (fused place + slot scatter
     # + CSR merge + rank/bound refresh in ONE dispatch, device buffers
-    # adopted from the graph's outputs).  None = AUTO: on for Pallas
-    # (accelerator) engines, where one kernel beats two dispatches +
-    # host round trips; off for the fused-XLA CPU engine, where the
+    # adopted from the graph's outputs).  None = AUTO: on only for
+    # engines built with the explicit fused_impl="pallas"; off for the
+    # default fused-XLA engine on every platform — on XLA-CPU the
     # graph's fixed O(state) cost (full-array carried-key repair scan,
     # functional whole-buffer updates) loses to the sparse host delta
-    # at steady state (measured in BENCH_ingest fused_dispatch rows).
-    # True/False force the arm either way; the staleness benchmarks pin
-    # False to keep exercising the delta machinery in isolation.
+    # (BENCH_ingest fused_dispatch rows), and on the TPU it has not
+    # been measured.  True/False force the arm either way; the
+    # staleness benchmarks pin False to keep exercising the delta
+    # machinery in isolation.
     fused_ingest_enabled: Optional[bool] = None
     # split commit: when the fused abort gate vetoes a batch, retry the
     # longest locally-clean PREFIX in-graph and replay only the
@@ -446,8 +442,8 @@ class Index:
         if wide and not exact:  # beyond 2^48: only the host is exact
             return BACKENDS["numpy-oracle"]
         # the fused single-dispatch path serves narrow and wide (hi/lo
-        # pair) keys on every platform; the engine picks the Pallas
-        # kernel vs the fused XLA graph by platform (engine.fused_impl)
+        # pair) keys on every platform (engine.fused_impl says which
+        # implementation: the fused XLA graph unless built otherwise)
         return BACKENDS["fused"]
 
     # ------------------------------------------------------------------
@@ -931,8 +927,8 @@ class Index:
         placement = "host"
         self._last_abort_reasons = ()
         enabled = self.fused_ingest_enabled
-        if enabled is None:  # auto: the fused write graph pays off on
-            enabled = (      # accelerator engines (see the field doc)
+        if enabled is None:  # auto: only explicit Pallas engines (see
+            enabled = (      # the field doc)
                 getattr(self._engine, "fused_impl", "xla") == "pallas")
         if enabled and self._fused_eligible(keys, payloads):
             prims, ok, state = self._fused_dispatch(keys, payloads)

@@ -50,23 +50,24 @@ Backend decision table (mirrored by ``repro.core.BACKENDS``)
 engine name    handle name     wide   search stage
 =============  ==============  =====  ====================================
 ``fused``      fused           yes    THE default device path, one lean
-                                      dispatch at every batch size:
-                                      * TPU: fused Pallas kernel — in-
-                                        kernel radix routing, windowed
-                                        search over VMEM tiles, CSR chain
-                                        epilogue, payload gather, per-tile
-                                        fallback compaction;
-                                      * CPU/GPU: fused XLA graph — one
-                                        bucket->slot-rank table collapses
-                                        route+predict+window into two
-                                        gathers + a ~log2(p99 occupancy)
-                                        bisect; escapes return as a MASK
-                                        and are patched in O(#escapes)
-                                        host numpy (no device compaction —
-                                        XLA-CPU scatters/cumsums are
-                                        scalar loops).
+                                      dispatch at every batch size, on
+                                      every platform: the fused XLA
+                                      graph — one bucket->slot-rank
+                                      table collapses route+predict+
+                                      window into two gathers + a
+                                      ~log2(p99 occupancy) bisect;
+                                      escapes return as a MASK and are
+                                      patched in O(#escapes) host numpy.
+                                      The fused Pallas kernel (in-kernel
+                                      radix routing, windowed search
+                                      over VMEM tiles, CSR chain
+                                      epilogue, per-tile fallback
+                                      compaction) serves only engines
+                                      built with ``fused_impl="pallas"``
+                                      — the TPU compiler refuses it
+                                      today (1-D in-kernel gathers).
 ``pallas``     pallas          no     LEGACY multi-op kernel (debug/ref;
-                                      ``interpret=True`` on CPU)
+                                      interpreted off-TPU)
 ``xla``        xla-windowed    yes    legacy multi-op windowed bisect /
                                       flat rank count (debug/reference;
                                       non-forced requests below
@@ -87,20 +88,21 @@ Ingest backend contract (device-side §5.3, single dispatch)
 -----------------------------------------------------------
 Writes can be a single fused dispatch, like reads.  On an eligible
 device-resident engine with the fused write graph enabled
-(``Index.fused_ingest_enabled`` — auto: ON for Pallas/accelerator
-engines, where one kernel beats two dispatches plus host round trips;
-OFF for the fused-XLA CPU engine, where the graph's fixed O(state)
-cost — full-array carried-key repair scan, functional whole-buffer
-updates — loses to the sparse host delta at steady state, measured in
-BENCH_ingest's ``fused_dispatch`` rows), ``Index.ingest`` issues ONE
-device invocation (``ops_gap.fused_ingest``, surfaced as
-``QueryEngine.fused_ingest``) whose graph chains four stages with no
-host round trip between them:
+(``Index.fused_ingest_enabled`` — auto: ON only for engines built with
+the explicit ``fused_impl="pallas"``; OFF for the default fused-XLA
+engine on every platform: on XLA-CPU the graph's fixed O(state) cost —
+full-array carried-key repair scan, functional whole-buffer updates —
+loses to the sparse host delta, measured in BENCH_ingest's
+``fused_dispatch`` rows, and on the TPU it has not been measured),
+``Index.ingest`` issues ONE device invocation (``ops_gap.fused_ingest``,
+surfaced as ``QueryEngine.fused_ingest``) whose graph chains four
+stages with no host round trip between them:
 
 1. **placement** — the shared per-key body
-   (``gap_place.ingest_place_body``; composed from the Pallas kernel on
-   TPU, inlined in the fused-XLA graph elsewhere) computes predicted
-   slot, occupancy, run boundaries (``pv``/``ub``), bracket, escape;
+   (``gap_place.ingest_place_body``; inlined in the fused-XLA graph, or
+   composed from the Pallas kernel on explicit Pallas engines) computes
+   predicted slot, occupancy, run boundaries (``pv``/``ub``), bracket,
+   escape;
 2. **slot arm** — scatter the bracketed-free keys/payloads into their
    slots and repair the carried keys with one reverse pair-min scan
    (the associative-scan twin of ``GappedArray._repair_carried``);

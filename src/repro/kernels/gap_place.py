@@ -38,10 +38,13 @@ has no guaranteed fused multiply-add), giving ~2^-45-relative products
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .lookup import resolve_interpret
 
 
 # ---------------------------------------------------------------------------
@@ -321,8 +324,10 @@ def _ingest_place_kernel(
 
     The frozen tables ride whole-array BlockSpecs (slot keys at f32 are
     4 B/slot — ~4 MiB/M slots, VMEM-resident like the lookup kernel's
-    segment tables; beyond that the fused-XLA variant serves).  Flags
-    pack free(1) | bracket(2) | escape(4).
+    segment tables), so the kernel only fits tables that fit VMEM;
+    nothing routes around that — engines serve the fused-XLA variant
+    unless this kernel is requested explicitly.  Flags pack free(1) |
+    bracket(2) | escape(4).
     """
     p, pv, ub, free, bracket, escape = ingest_place_body(
         x_hi_ref[:], x_lo_ref[:],
@@ -372,13 +377,14 @@ def fused_ingest_body(
     max_chain: int,
     key_wide: bool,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     key_tile: int = 512,
 ):
     """The single-dispatch §5.3 ingest graph: placement -> partition ->
     slot scatter + carried repair -> CSR-merge scatter -> rank-row /
-    window-bound refresh, all in ONE jitted XLA graph (the Pallas
-    placement kernel composes inside it on TPU — still one dispatch).
+    window-bound refresh, all in ONE jitted XLA graph (with
+    ``use_pallas`` the Pallas placement kernel composes inside it —
+    still one dispatch).
 
     The graph serves exactly the batches whose host demotion closure is
     TRIVIAL — no collision groups (no two batch keys predict the same
@@ -429,7 +435,7 @@ def fused_ingest_body(
             x_hi, x_lo, segk_hi, segk_lo, slope_hi, slope_lo,
             icept_hi, icept_lo, slot_hi, slot_lo, link_offsets,
             link_hi, link_lo, key_tile=min(key_tile, B),
-            n_slots=n_slots, interpret=interpret)
+            n_slots=n_slots, interpret=resolve_interpret(interpret))
         free = (flags & 1) != 0
         bracket = (flags & 2) != 0
         escape = (flags & 4) != 0

@@ -33,11 +33,21 @@ VMEM budget per grid step (defaults q_tile=256, w_tile=2048, K<=8192):
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Pallas interpret mode for a kernel entry point: an explicit
+    request wins; otherwise interpret only off the TPU, so no kernel
+    runs in Python on a TPU unless a caller asks for it."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
 
 __all__ = ["lookup_kernel_call", "fused_lookup_call"]
 
@@ -193,8 +203,9 @@ def lookup_kernel_call(
 #   3. fused epilogue in the same kernel: slot->payload gather from the
 #      payload window blocks plus the ceil(log2(max_chain + 1))-trip CSR
 #      chain bisect over the VMEM-resident link tables (link tables ride
-#      whole — the engine routes to the fused XLA path when they exceed
-#      the VMEM budget);
+#      whole, so this kernel only fits indexes whose link tables fit the
+#      VMEM budget — nothing routes around that; the engine serves the
+#      fused XLA graph unless this kernel is requested explicitly);
 #   4. in-kernel fallback flagging AND per-tile compaction: escaped
 #      queries are compacted into a per-tile local index list + count via
 #      branchless (q_tile, q_tile) prefix-count/one-hot matrices (VPU/MXU

@@ -3,20 +3,19 @@
 
 ``IndexArrays`` freezes the host state of an index into f32/i32 device
 arrays; ``batched_lookup`` / ``QueryEngine`` run the lookup.  The
-DEFAULT path is the fused single dispatch (backend ``"fused"``):
+DEFAULT path, on every platform, is the fused single dispatch (backend
+``"fused"``) served by the fused XLA graph (``_fused_pipeline``): a
+precomputed bucket->slot-rank table collapses route+predict+window into
+two gathers plus a ~log2(p99 bucket occupancy) fixed-trip bisect, the
+epilogue is fused behind it, and the escape MASK rides home with the
+outputs for an O(#escapes) host-numpy patch.
 
-* **TPU**: the fused Pallas kernel (lookup.py) — radix routing, bounded
-  window search, CSR chain epilogue, payload gather, and per-tile
-  fallback compaction in ONE ``pallas_call``; escaped queries are
-  re-resolved through a compacted fixed-capacity buffer behind a
-  ``lax.cond``;
-* **CPU/GPU**: the fused XLA graph (``_fused_pipeline``) — a
-  precomputed bucket->slot-rank table collapses route+predict+window
-  into two gathers plus a ~log2(p99 bucket occupancy) fixed-trip
-  bisect, the epilogue is fused behind it, and the escape MASK rides
-  home with the outputs for an O(#escapes) host-numpy patch (XLA-CPU
-  lowers cumsum/scatter to scalar loops, so device-side compaction
-  costs more than the whole search there).
+The fused Pallas kernel (lookup.py — radix routing, bounded window
+search, CSR chain epilogue, payload gather and per-tile fallback
+compaction in ONE ``pallas_call``) runs only when a caller asks for it
+(``QueryEngine(fused_impl="pallas")``): the TPU compiler refuses it
+today (1-D in-kernel gathers, a rank-1 count block that is not a
+multiple of 128, whole-table VMEM blocks).
 
 A trailing bracket validation (``slot_key[r] <= q < slot_key[r+1]``)
 makes the fused result exact INDEPENDENT of the routing tables: a stale
@@ -56,14 +55,16 @@ composite keys) are carried as an (hi, lo) f32 pair with
 ``lo = key - f64(hi)``; lexicographic pair order equals numeric order
 and the representation is exact for integer keys below 2^48.  The XLA
 windowed and oracle backends compare pairs end to end (search, window
-edges, compacted fallback, CSR chain bisect); the Pallas kernel is
-narrow-key only — the capability registry (repro.core.handle) routes
-wide-key lookups to ``xla-windowed``.
+edges, compacted fallback, CSR chain bisect); the legacy Pallas kernel
+is narrow-key only — the capability registry (repro.core.handle)
+refuses it for wide-key indexes.
 
 Everything is shape-static and jit-friendly; ``QueryEngine`` buckets
 query shapes so the serving path stops re-tracing per batch.
-``interpret=True`` runs the Pallas kernel body in Python on CPU (how
-this container validates it — the TPU is the deploy target).
+``interpret`` runs a Pallas kernel body in Python; every entry point
+defaults it from the platform (``lookup.resolve_interpret``: on off the
+TPU, off on it), so no kernel runs interpreted on a TPU unless a caller
+asks.
 """
 
 from __future__ import annotations
@@ -77,7 +78,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref as _ref
-from .lookup import fused_lookup_call, lookup_kernel_call
+from .lookup import fused_lookup_call, lookup_kernel_call, resolve_interpret
 
 __all__ = ["IndexArrays", "QueryEngine", "batched_lookup",
            "build_radix_router", "from_learned_index", "freeze_state",
@@ -1322,7 +1323,7 @@ def batched_lookup(
     w_tile: int = 2048,
     seg_chunk: int = 512,
     win_chunk: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     use_kernel: bool = True,
     backend: Optional[str] = None,
     err_hi_by_seg=None,
@@ -1331,8 +1332,9 @@ def batched_lookup(
 ):
     """Full device lookup: payloads (-1 = miss), slots, found, #fallbacks.
 
-    ``backend`` selects the search stage: "pallas" (TPU kernel;
-    ``interpret=True`` on CPU), "xla" (windowed bisect, permutation-free)
+    ``backend`` selects the search stage: "pallas" (legacy kernel;
+    ``interpret`` defaults from the platform), "xla" (windowed bisect,
+    permutation-free)
     or "oracle" (full searchsorted).  Default: "pallas" when
     ``use_kernel`` else "oracle"; wide-key (``arrays.key_wide``) batches
     requesting "pallas" route to "xla".  ``err_lo_by_seg`` /
@@ -1343,6 +1345,7 @@ def batched_lookup(
     Pallas path.  ``found`` marks present keys (first-level OR chain).
     """
     backend = backend or ("pallas" if use_kernel else "oracle")
+    interpret = resolve_interpret(interpret)
     if backend not in ("pallas", "xla", "oracle", "fused", "fused-pallas"):
         raise ValueError(f"unknown backend {backend!r}")
     if backend == "pallas" and arrays.key_wide:
@@ -1742,15 +1745,15 @@ class QueryEngine:
                  win_chunk: int = 512, fb_frac: float = FB_FRAC,
                  min_bucket: int = 256, xla_min_bucket: int = 8192,
                  fused_flat_max_bucket: int = 8192):
-        on_tpu = jax.default_backend() == "tpu"
         self.arrays = arrays
         # the fused single-dispatch path is the default everywhere; the
         # multi-op "xla"/"pallas" stages stay as debug/reference backends
         self.backend = backend or "fused"
-        # which fused implementation serves: the fused Pallas kernel on
-        # TPU, the minimal-op fused XLA graph elsewhere
-        self.fused_impl = fused_impl or ("pallas" if on_tpu else "xla")
-        self.interpret = (not on_tpu) if interpret is None else interpret
+        # which fused implementation serves: the fused XLA graph on every
+        # platform; the fused Pallas kernel only on explicit request (the
+        # TPU compiler refuses it — see the module doc)
+        self.fused_impl = fused_impl or "xla"
+        self.interpret = resolve_interpret(interpret)
         self.q_tile = q_tile
         self.w_tile = w_tile
         self.seg_chunk = seg_chunk
@@ -1918,8 +1921,9 @@ class QueryEngine:
         """Device §5.3 ingest placement against the frozen arrays: the
         per-key primitives ``GappedArray.insert_batch`` consumes, plus
         the escape mask for the O(#escapes) host patch (see
-        ``ops_gap.ingest_place``).  Served by the Pallas kernel on TPU
-        and the fused-XLA graph elsewhere, like ``fused`` lookups."""
+        ``ops_gap.ingest_place``).  Served by the fused-XLA graph, or by
+        the Pallas kernel when the engine was built with
+        ``fused_impl="pallas"``, like ``fused`` lookups."""
         from .ops_gap import ingest_place as _place
         return _place(self.arrays, keys,
                       impl=("pallas" if self.fused_impl == "pallas"

@@ -5,7 +5,7 @@ stage — see ``ingest_place`` for the latter's contract)."""
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +13,7 @@ import numpy as np
 
 from .gap_place import (fused_ingest_body, gap_place_call,
                         ingest_place_body, ingest_place_call)
+from .lookup import resolve_interpret
 from .ops import _pad_pow
 
 
@@ -52,7 +53,7 @@ def prepare_gap_tables(x: np.ndarray, y: np.ndarray, plm, rho: float,
 
 def gap_positions_device(x: np.ndarray, plm, rho: float, *,
                          key_tile: int = 1024, seg_chunk: int = 512,
-                         interpret: bool = True) -> np.ndarray:
+                         interpret: Optional[bool] = None) -> np.ndarray:
     """Device Eq. 3: returns monotone target positions for all keys."""
     x = np.asarray(x, np.float64)
     y = np.arange(x.shape[0], dtype=np.float64)
@@ -61,7 +62,8 @@ def gap_positions_device(x: np.ndarray, plm, rho: float, *,
     out = gap_place_call(
         jnp.asarray(xp), jnp.asarray(segk), jnp.asarray(base),
         jnp.asarray(x0), jnp.asarray(scale),
-        key_tile=key_tile, seg_chunk=seg_chunk, interpret=interpret,
+        key_tile=key_tile, seg_chunk=seg_chunk,
+        interpret=resolve_interpret(interpret),
     )
     yg = np.asarray(out)[: x.shape[0]].astype(np.float64)
     return np.maximum.accumulate(yg)  # same boundary-tie guard as core
@@ -87,8 +89,8 @@ def _ingest_place_xla(x_hi, x_lo, segk_hi, segk_lo, slope_hi, slope_lo,
                       icept_hi, icept_lo, slot_hi, slot_lo, link_offsets,
                       link_hi, link_lo, *, n_slots):
     """Fused-XLA variant: the SAME per-key body the Pallas kernel runs,
-    over the whole batch in one lean dispatch (the CPU/GPU half of the
-    ingest-place backend, mirroring the fused lookup's split)."""
+    over the whole batch in one lean dispatch (the default ingest-place
+    backend on every platform, mirroring the fused lookup)."""
     return ingest_place_body(
         x_hi, x_lo, segk_hi, segk_lo, slope_hi, slope_lo, icept_hi,
         icept_lo, slot_hi, slot_lo, link_offsets, link_hi, link_lo,
@@ -96,7 +98,7 @@ def _ingest_place_xla(x_hi, x_lo, segk_hi, segk_lo, slope_hi, slope_lo,
 
 
 def ingest_place(arrays, keys, *, impl: str = "xla",
-                 interpret: bool = True, key_tile: int = 512):
+                 interpret: Optional[bool] = None, key_tile: int = 512):
     """Device §5.3 ingest placement: per-key placement primitives for an
     insert batch, computed against the FROZEN device arrays.
 
@@ -113,8 +115,9 @@ def ingest_place(arrays, keys, *, impl: str = "xla",
     batch key must be pair-exact (reconstructed exactly by its f32
     hi/lo split — all integer keys < 2^48), so every pair compare below
     equals the host's f64 compare; narrow (f32-exact) indexes run with
-    zero lo arrays.  ``impl`` picks the Pallas kernel ("pallas", the
-    TPU half) or the fused-XLA graph ("xla" — CPU/GPU); both run ONE
+    zero lo arrays.  ``impl`` picks the fused-XLA graph ("xla", the
+    default on every platform) or the Pallas kernel ("pallas", explicit
+    request only — the TPU compiler refuses it today); both run ONE
     shared per-key body, so they are bit-identical by construction.
     """
     from .ops import split_key_pair
@@ -145,7 +148,7 @@ def ingest_place(arrays, keys, *, impl: str = "xla",
             arrays.seg_slope_lo, arrays.seg_icept, arrays.seg_icept_lo,
             slot_hi, slot_lo, arrays.link_offsets, link_hi, link_lo,
             key_tile=key_tile, n_slots=arrays.n_slots,
-            interpret=interpret)
+            interpret=resolve_interpret(interpret))
         flags = np.asarray(flags)[:n_b]
         free = (flags & 1).astype(bool)
         bracket = (flags & 2).astype(bool)
@@ -206,7 +209,7 @@ def _fused_ingest_xla(
 
 def fused_ingest(arrays, keys, payloads, *, rank_table, rank_bounds_hi,
                  rank_bounds_lo, rank_scale, elo, ehi, max_chain,
-                 impl: str = "xla", interpret: bool = True,
+                 impl: str = "xla", interpret: Optional[bool] = None,
                  min_bucket: int = 256, key_tile: int = 512):
     """Single-dispatch device-resident ingest.
 
@@ -261,7 +264,7 @@ def fused_ingest(arrays, keys, payloads, *, rank_table, rank_bounds_hi,
         rank_bounds_lo, rank_scale, elo, ehi,
         n_slots=arrays.n_slots, max_chain=int(max_chain),
         key_wide=key_wide, use_pallas=(impl == "pallas"),
-        interpret=interpret, key_tile=key_tile)
+        interpret=resolve_interpret(interpret), key_tile=key_tile)
     (p, pv, ub, free, bracket, escape, ok, reasons, n_slot, n_chain,
      seg, dlt) = outs[:12]
     (slot_key, slot_key_lo, payload, payload_hi, link_offsets, link_keys,

@@ -42,7 +42,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import ops as _ops
@@ -421,12 +420,12 @@ class ShardFanout:
 
         qspec = P("data")
         aspecs = {k: self._specs[k] for k in self.stacked}
-        mapped = shard_map(
+        mapped = jax.shard_map(
             block, mesh=self.mesh,
             in_specs=(qspec, qspec, P(None), P(None), P(None), aspecs),
             out_specs=(qspec, qspec, qspec, qspec, qspec, qspec,
                        P("data"), P("data")),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(mapped)
 
     # ------------------------------------------------------------------

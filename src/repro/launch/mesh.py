@@ -9,12 +9,21 @@ touches jax device state.  Shapes per the assignment:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: the repo's sharding code
+    (``with_sharding_constraint``, ``NamedSharding`` placement, the
+    fan-out's ``shard_map``) is written for compiler-propagated
+    shardings, while ``make_mesh`` defaults to ``Explicit`` axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_for(n_devices: int, model_parallel: int = 1):
@@ -26,6 +35,6 @@ def make_mesh_for(n_devices: int, model_parallel: int = 1):
     model_parallel = max(1, min(model_parallel, n_devices))
     while n_devices % model_parallel:
         model_parallel -= 1
-    return jax.make_mesh(
+    return _auto_mesh(
         (n_devices // model_parallel, model_parallel), ("data", "model")
     )

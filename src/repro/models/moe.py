@@ -103,7 +103,6 @@ def moe_ep_shardmap(params, x, *, top_k: int, mesh, model_axis: str = "model",
     GSPMD-derived gather/scatter collectives.
     """
     from jax.sharding import PartitionSpec as PS
-    from jax.experimental.shard_map import shard_map
 
     E = params["router"].shape[1]
     ep = mesh.shape[model_axis]
@@ -147,7 +146,7 @@ def moe_ep_shardmap(params, x, *, top_k: int, mesh, model_axis: str = "model",
         gathered = gathered.reshape(t_l, top_k, D) * weights[..., None]
         return gathered.sum(axis=1).reshape(b_l, s_l, D)
 
-    return shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -160,5 +159,5 @@ def moe_ep_shardmap(params, x, *, top_k: int, mesh, model_axis: str = "model",
             PS(batch_axes, model_axis, None),
         ),
         out_specs=PS(batch_axes, model_axis, None),
-        check_rep=False,
+        check_vma=False,
     )(params["router"], params["w_gate"], params["w_up"], params["w_down"], x)

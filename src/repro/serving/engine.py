@@ -86,13 +86,15 @@ class MicroBatchQueue:
       its ticket IMMEDIATELY to a typed ``core.Overloaded`` result
       (``stats["shed"]``) — explicit backpressure, never a silent hang
       and never an unbounded queue.
-    * ingest retry — a raising ``index.ingest`` is retried
+    * ingest retry — an ingest that raises the fault harness's
+      transient ``robustness.faults.InjectedFault`` is retried
       ``ingest_retries`` times with exponential backoff, the final
       attempt forcing the host partition path
-      (``fused_ingest_enabled=False``, restored after) so a
-      misbehaving fused write graph degrades to the proven host path
-      instead of failing the request.  ``InjectedCrash`` (process
-      death) always propagates.
+      (``fused_ingest_enabled=False``, restored after).  Every other
+      error propagates on its first occurrence — above all a
+      ``jax.errors.JaxRuntimeError`` from compiling or running on the
+      device, which a host retry would hide.  ``InjectedCrash``
+      (process death) always propagates.
     """
 
     def __init__(self, index, min_bucket: int = 512,
@@ -234,12 +236,13 @@ class MicroBatchQueue:
 
         lock-held: _lock (only reached from flush()).
 
-        Retries transient
-        ``RuntimeError``s only — ``InjectedCrash`` (process death) and
-        caller bugs (``KeyError``/``ValueError``: duplicate keys, shape
-        mismatches) propagate immediately, since replaying them cannot
-        succeed and may double-apply."""
-        from ..robustness.faults import InjectedCrash
+        Retries the harness's transient ``InjectedFault`` only.
+        ``InjectedCrash`` (process death), caller bugs
+        (``KeyError``/``ValueError``: duplicate keys, shape mismatches)
+        and device errors propagate immediately: replaying them cannot
+        succeed, may double-apply, or would serve a device failure from
+        the host as if it had succeeded."""
+        from ..robustness.faults import InjectedCrash, InjectedFault
         last: Optional[BaseException] = None
         for attempt in range(self.ingest_retries + 1):
             force_host = attempt > 0 and attempt == self.ingest_retries
@@ -254,7 +257,7 @@ class MicroBatchQueue:
                 return target.ingest(keys, pays)
             except InjectedCrash:
                 raise
-            except RuntimeError as e:
+            except InjectedFault as e:
                 last = e
                 self.stats["ingest_retries"] += 1
                 time.sleep(self.retry_backoff_ms * (2 ** attempt) / 1e3)

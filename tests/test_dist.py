@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as PS
+from jax.sharding import AxisType, PartitionSpec as PS
 
 from repro.configs import ARCHS
 from repro.dist.partitioning import (
@@ -20,7 +20,8 @@ from repro.models import build_model
 
 
 def _mesh2d(data=1, model=1):
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_pspec_basic_rules():

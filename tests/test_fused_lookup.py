@@ -198,3 +198,42 @@ def test_delta_refresh_tracks_refreeze_fallback_rate():
     assert np.array_equal(res_d.payloads, res_f.payloads)
     assert np.array_equal(res_d.found, res_f.found)
     assert res_d.fallbacks <= 2 * max(res_f.fallbacks, 1)
+
+
+def test_tpu_defaults_serve_the_compiled_xla_graph(monkeypatch):
+    """On a TPU the default device path is the fused XLA graph, no
+    kernel entry point defaults to interpret mode, and the auto rule
+    leaves the fused ingest graph off.  The platform is steered by
+    patching ``jax.default_backend``; the graph itself runs on the CPU."""
+    import inspect
+
+    import jax
+
+    from repro.kernels import gap_place, ops_gap
+    from repro.kernels.lookup import resolve_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True  # an explicit request wins
+    for fn in (batched_lookup, ops_gap.gap_positions_device,
+               ops_gap.ingest_place, ops_gap.fused_ingest,
+               gap_place.fused_ingest_body):
+        assert inspect.signature(fn).parameters["interpret"].default is None
+
+    rng = np.random.default_rng(7)
+    keys = np.unique(rng.choice(2 ** 40, 20_000, replace=False)
+                     ).astype(np.float64)
+    idx = Index.build(keys, method="pgm", gap_rho=0.15)
+    eng = idx.sync_device()
+    assert (eng.fused_impl, eng.interpret, eng.backend) == ("xla", False,
+                                                          "fused")
+    q = _mixed_queries(rng, keys)
+    res = idx.lookup(q)
+    assert res.backend == "fused" and eng.last_stage == "fused"
+    assert np.array_equal(res.payloads, idx.gapped.lookup_batch(q))
+    mids = np.setdiff1d(keys[:-1] + np.rint(np.diff(keys) * 0.5),
+                        keys)[:1024]
+    rep = idx.ingest(mids, 10 ** 6 + np.arange(mids.size))
+    assert rep.device != "fused"  # the fused write graph stayed off
+    res = idx.lookup(mids)
+    assert res.backend == "fused" and res.found.all()

@@ -291,6 +291,47 @@ def test_ingest_final_retry_falls_back_to_host_path():
     q.close()
 
 
+@pytest.mark.parametrize("error", ["RuntimeError", "JaxRuntimeError"])
+def test_device_error_propagates_without_retry(error):
+    """Only the harness's transient ``InjectedFault`` is retried: any
+    other ``RuntimeError`` from the index (e.g. a ``JaxRuntimeError``
+    from compiling or running on the device) propagates on its first
+    attempt, never rerouted onto the host path."""
+    import jax
+
+    exc = {"RuntimeError": RuntimeError,
+           "JaxRuntimeError": jax.errors.JaxRuntimeError}[error]
+    assert issubclass(exc, RuntimeError)
+    idx, keys = _mk_index(n=4_000)
+    calls = []
+
+    def failing_ingest(k, p):
+        calls.append(int(k.shape[0]))
+        raise exc("device program failed to compile")
+
+    idx.ingest = failing_ingest
+    q = MicroBatchQueue(idx, ingest_retries=2, retry_backoff_ms=0.1)
+    t = q.submit_ingest(_fresh(keys, 8), np.arange(8, dtype=np.int64))
+    with pytest.raises(exc, match="failed to compile"):
+        q.result(t)
+    assert calls == [8]
+    assert q.stats["ingest_retries"] == 0
+    assert q.stats["host_fallbacks"] == 0
+    assert idx.fused_ingest_enabled is None  # never forced to the host
+    q.close()
+
+    # the transient harness fault is still retried, and then succeeds
+    idx2, keys2 = _mk_index(n=4_000)
+    inj = FaultInjector({("ingest", 0): "abort"})
+    q2 = MicroBatchQueue(idx2, faults=inj, ingest_retries=2,
+                         retry_backoff_ms=0.1)
+    b = _fresh(keys2, 8)
+    assert q2.result(q2.submit_ingest(b, np.arange(8, dtype=np.int64))).n == 8
+    assert q2.stats["ingest_retries"] == 1
+    assert q2.stats["host_fallbacks"] == 0
+    q2.close()
+
+
 def test_injected_crash_propagates_through_retry():
     idx, keys = _mk_index(n=4_000)
     inj = FaultInjector({("ingest", 0): "crash"})
