@@ -75,6 +75,7 @@ import time
 from typing import Dict, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import gaps as _gaps
 from . import mdl as _mdl
@@ -453,7 +454,8 @@ class Index:
         """Full rebuild of the frozen device state (arrays + query-safe
         window bounds + host mirror) at the current epoch."""
         from ..kernels import ops as _ops
-        self._engine, self._mirror = _ops.freeze_state(self)
+        with TraceAnnotation("repro.ops.freeze"):
+            self._engine, self._mirror = _ops.freeze_state(self)
         self._device_epoch = self.epoch
         self._pending_touch = []  # fresh bounds cover everything logged
         self.stats["refreezes"] += 1
@@ -477,30 +479,34 @@ class Index:
 
     def _sync_device(self, prefer_delta: bool = True):
         """Bring the device state to the current epoch (delta if allowed
-        and possible, else refreeze)."""
-        if self._engine is None:
-            return self.refreeze()
-        if self._device_epoch == self.epoch:
+        and possible, else refreeze).  Only a sync with work opens the
+        ``repro.index.sync`` span."""
+        if self._engine is not None and self._device_epoch == self.epoch:
             return self._engine
-        from ..kernels import ops as _ops
-        if prefer_delta:
-            new_arrays, n_elems, touched_keys = _ops.delta_update(
-                self._engine.arrays, self._mirror, self)
-            if new_arrays is not None:
-                self._engine.swap_arrays(new_arrays)
-                self._device_epoch = self.epoch
-                self.stats["delta_updates"] += 1
-                self.stats["delta_elems"] += n_elems
-                pending = ([np.asarray(touched_keys, np.float64)]
-                           if touched_keys is not None else [])
-                pending += [np.asarray(a, np.float64)
-                            for a in self._pending_touch]
-                self._pending_touch = []
-                self._refresh_window_bounds(
-                    np.concatenate(pending) if pending
-                    else np.zeros(0, np.float64))
-                return self._engine
-        return self.refreeze()
+        with TraceAnnotation("repro.index.sync"):
+            if self._engine is None:
+                return self.refreeze()
+            from ..kernels import ops as _ops
+            if prefer_delta:
+                with TraceAnnotation("repro.ops.delta_update"):
+                    new_arrays, n_elems, touched_keys = _ops.delta_update(
+                        self._engine.arrays, self._mirror, self)
+                if new_arrays is not None:
+                    self._engine.swap_arrays(new_arrays)
+                    self._device_epoch = self.epoch
+                    self.stats["delta_updates"] += 1
+                    self.stats["delta_elems"] += n_elems
+                    pending = ([np.asarray(touched_keys, np.float64)]
+                               if touched_keys is not None else [])
+                    pending += [np.asarray(a, np.float64)
+                                for a in self._pending_touch]
+                    self._pending_touch = []
+                    with TraceAnnotation("repro.index.bound_refresh"):
+                        self._refresh_window_bounds(
+                            np.concatenate(pending) if pending
+                            else np.zeros(0, np.float64))
+                    return self._engine
+            return self.refreeze()
 
     def _refresh_window_bounds(self, touched_keys) -> None:
         """Incremental per-segment window-bound refresh after a delta
@@ -565,42 +571,44 @@ class Index:
         by batch size / platform / key width.  ``queries_sorted=True``
         skips the sort round trip on the Pallas path.
         """
-        queries = np.asarray(queries, np.float64)
-        spec = self.resolve_backend(queries.shape[0], backend)
-        self.stats["lookups"] += 1
-        if not spec.device:
-            if self.gapped is not None:
-                pay, slots, found = self.gapped.lookup_batch(queries,
-                                                             full=True)
-                return host_lookup_result(pay, slots, found, spec.name,
+        with TraceAnnotation("repro.index.lookup"):
+            queries = np.asarray(queries, np.float64)
+            spec = self.resolve_backend(queries.shape[0], backend)
+            self.stats["lookups"] += 1
+            if not spec.device:
+                if self.gapped is not None:
+                    pay, slots, found = self.gapped.lookup_batch(queries,
+                                                                 full=True)
+                    return host_lookup_result(pay, slots, found, spec.name,
+                                              self.epoch)
+                pos, probes = _sampling.exponential_search(
+                    self.keys, queries, self.predict(queries))
+                self.stats["search_probes"] += probes
+                found = self.keys[pos] == queries
+                pay = np.where(found, pos, -1)
+                return host_lookup_result(pay, pos, found, spec.name,
                                           self.epoch)
-            pos, probes = _sampling.exponential_search(
-                self.keys, queries, self.predict(queries))
-            self.stats["search_probes"] += probes
-            found = self.keys[pos] == queries
-            pay = np.where(found, pos, -1)
-            return host_lookup_result(pay, pos, found, spec.name, self.epoch)
-        engine = self._sync_device()
-        esc0 = engine.stats["oracle_escapes"]
-        out, slot, found, fb = engine.lookup(
-            queries, queries_sorted=queries_sorted,
-            backend=spec.engine_backend, force_backend=backend is not None)
-        # label the search stage that ACTUALLY ran: the engine's
-        # size-aware scheduler may run the device oracle for small
-        # default-resolved legacy-xla buckets (explicit requests are
-        # forced), and overflow escapes land on the device oracle
-        stage = {"fused": "fused", "pallas": "pallas",
-                 "xla": "xla-windowed",
-                 "oracle": "device-oracle"}[engine.last_stage]
-        return LookupResult(
-            payloads=np.asarray(out).astype(np.int64),
-            slots=np.asarray(slot).astype(np.int64),
-            found=np.asarray(found, bool),
-            backend=stage,
-            epoch=self.epoch,
-            fallbacks=int(fb),
-            oracle_escapes=engine.stats["oracle_escapes"] - esc0,
-        )
+            engine = self._sync_device()
+            esc0 = engine.stats["oracle_escapes"]
+            out, slot, found, fb = engine.lookup(
+                queries, queries_sorted=queries_sorted,
+                backend=spec.engine_backend, force_backend=backend is not None)
+            # label the search stage that ACTUALLY ran: the engine's
+            # size-aware scheduler may run the device oracle for small
+            # default-resolved legacy-xla buckets (explicit requests are
+            # forced), and overflow escapes land on the device oracle
+            stage = {"fused": "fused", "pallas": "pallas",
+                     "xla": "xla-windowed",
+                     "oracle": "device-oracle"}[engine.last_stage]
+            return LookupResult(
+                payloads=np.asarray(out).astype(np.int64),
+                slots=np.asarray(slot).astype(np.int64),
+                found=np.asarray(found, bool),
+                backend=stage,
+                epoch=self.epoch,
+                fallbacks=int(fb),
+                oracle_escapes=engine.stats["oracle_escapes"] - esc0,
+            )
 
     # ------------------------------------------------------------------
     # writes (§5.3 dynamic ops — need a gapped build)
@@ -755,7 +763,9 @@ class Index:
         eng = self._engine
         cand = np.asarray(prims["free"], bool) & np.asarray(
             prims["bracket"], bool)
-        counts = self.gapped.insert_batch(keys, payloads, placements=prims)
+        with TraceAnnotation("repro.index.insert"):
+            counts = self.gapped.insert_batch(keys, payloads,
+                                              placements=prims)
         self._key_caps_after_batch(keys)
         self.stats["ingests"] += 1
         if (counts["contested"] != 0 or counts["slot"] != state["n_slot"]
@@ -853,8 +863,9 @@ class Index:
         pk, pp = keys[:k], payloads[:k]
         if not self._fused_eligible(pk, pp):
             return None
-        prims2, esc2, ok2, reasons2, state2 = self._engine.fused_ingest(
-            pk, pp)
+        with TraceAnnotation("repro.index.place"):
+            prims2, esc2, ok2, reasons2, state2 = (
+                self._engine.fused_ingest(pk, pp))
         if not ok2:
             self.stats["split_commit_misses"] = (
                 self.stats.get("split_commit_misses", 0) + 1)
@@ -865,8 +876,10 @@ class Index:
         # remainder replays against the post-commit state (fresh
         # placements — the prefix moved slots under it)
         rk, rp = keys[k:], payloads[k:]
-        rprims = self._device_placements(rk)
-        counts = self.gapped.insert_batch(rk, rp, placements=rprims)
+        with TraceAnnotation("repro.index.place"):
+            rprims = self._device_placements(rk)
+        with TraceAnnotation("repro.index.insert"):
+            counts = self.gapped.insert_batch(rk, rp, placements=rprims)
         self._key_caps_after_batch(rk)
         self._log_touch(rk)
         device = rep1.device
@@ -919,74 +932,81 @@ class Index:
         rows, capacity overflows, duplicates) — those batches fall back
         to the host partition REUSING the same dispatch's primitives.
         """
-        self._need_gapped()
-        t0 = time.perf_counter()
-        keys = np.atleast_1d(np.asarray(keys, np.float64))
-        payloads = np.atleast_1d(np.asarray(payloads, np.int64))
-        prims = None
-        placement = "host"
-        self._last_abort_reasons = ()
-        enabled = self.fused_ingest_enabled
-        if enabled is None:  # auto: only explicit Pallas engines (see
-            enabled = (      # the field doc)
-                getattr(self._engine, "fused_impl", "xla") == "pallas")
-        if enabled and self._fused_eligible(keys, payloads):
-            prims, ok, state = self._fused_dispatch(keys, payloads)
-            placement = "device"
-            if ok:
-                return self._commit_fused(keys, payloads, prims, state, t0)
-            # split commit only helps when the veto is attributable to
-            # specific rows; a purely capacity-based veto (static chain/
-            # link headroom) vetoes any same-shaped prefix too, so those
-            # keep the one-dispatch abort contract
-            cap_only = set(self._last_abort_reasons) <= {
-                "chain_overflow", "link_overflow"}
-            if self.fused_split_commit and not cap_only:
-                rep = self._try_split_commit(keys, payloads, prims, t0)
-                if rep is not None:
-                    return rep
-        if prims is None:
-            prims = self._device_placements(keys)
-            placement = ("host" if prims is None
-                         else getattr(self, "_placement_mode", "device"))
-        counts = self.gapped.insert_batch(keys, payloads, placements=prims)
-        self._key_caps_after_batch(keys)
-        self._log_touch(keys)
-        self.stats["ingests"] += 1
-        device = "none"
-        elems = 0
-        if self._engine is not None:
-            wide, exact = self._key_caps()
-            if wide and not exact:
-                # ingested keys outgrew the hi/lo pair's exactness: the
-                # device can no longer answer exactly — drop the frozen
-                # state; the registry now routes every lookup host-side
-                self._engine = None
-                self._mirror = None
-                self._device_epoch = -1
-            else:
-                contested_frac = counts["contested"] / max(keys.shape[0], 1)
-                want_refreeze = (
-                    contested_frac > self.refreeze_contested_frac
-                    or self._link_growth_fraction()
-                    > self.refreeze_link_growth)
-                before = (self.stats["delta_updates"],
-                          self.stats["refreezes"],
-                          self.stats["delta_elems"])
-                self._sync_device(prefer_delta=not want_refreeze)
-                if self.stats["delta_updates"] > before[0]:
-                    device = "delta"
-                    elems = self.stats["delta_elems"] - before[2]
-                elif self.stats["refreezes"] > before[1]:
-                    device = "refreeze"
-        return IngestReport(
-            n=int(keys.shape[0]), slot=counts["slot"], chain=counts["chain"],
-            contested=counts["contested"], epoch=self.epoch, device=device,
-            device_elems=elems, seconds=time.perf_counter() - t0,
-            placement=placement,
-            abort_reasons=getattr(self, "_last_abort_reasons", ()),
-            fused_aborts=self.stats.get("fused_abort_total", 0),
-            split_commits=self.stats.get("split_commits", 0))
+        with TraceAnnotation("repro.index.ingest"):
+            self._need_gapped()
+            t0 = time.perf_counter()
+            keys = np.atleast_1d(np.asarray(keys, np.float64))
+            payloads = np.atleast_1d(np.asarray(payloads, np.int64))
+            prims = None
+            placement = "host"
+            self._last_abort_reasons = ()
+            enabled = self.fused_ingest_enabled
+            if enabled is None:  # auto: only explicit Pallas engines (see
+                enabled = (      # the field doc)
+                    getattr(self._engine, "fused_impl", "xla") == "pallas")
+            if enabled and self._fused_eligible(keys, payloads):
+                with TraceAnnotation("repro.index.place"):
+                    prims, ok, state = self._fused_dispatch(keys, payloads)
+                placement = "device"
+                if ok:
+                    return self._commit_fused(keys, payloads, prims, state, t0)
+                # split commit only helps when the veto is attributable to
+                # specific rows; a purely capacity-based veto (static chain/
+                # link headroom) vetoes any same-shaped prefix too, so those
+                # keep the one-dispatch abort contract
+                cap_only = set(self._last_abort_reasons) <= {
+                    "chain_overflow", "link_overflow"}
+                if self.fused_split_commit and not cap_only:
+                    rep = self._try_split_commit(keys, payloads, prims, t0)
+                    if rep is not None:
+                        return rep
+            if prims is None:
+                with TraceAnnotation("repro.index.place"):
+                    prims = self._device_placements(keys)
+                placement = ("host" if prims is None
+                             else getattr(self, "_placement_mode", "device"))
+            with TraceAnnotation("repro.index.insert"):
+                counts = self.gapped.insert_batch(keys, payloads,
+                                                  placements=prims)
+            self._key_caps_after_batch(keys)
+            self._log_touch(keys)
+            self.stats["ingests"] += 1
+            device = "none"
+            elems = 0
+            if self._engine is not None:
+                wide, exact = self._key_caps()
+                if wide and not exact:
+                    # ingested keys outgrew the hi/lo pair's exactness: the
+                    # device can no longer answer exactly — drop the frozen
+                    # state; the registry now routes every lookup host-side
+                    self._engine = None
+                    self._mirror = None
+                    self._device_epoch = -1
+                else:
+                    contested_frac = (counts["contested"]
+                                      / max(keys.shape[0], 1))
+                    want_refreeze = (
+                        contested_frac > self.refreeze_contested_frac
+                        or self._link_growth_fraction()
+                        > self.refreeze_link_growth)
+                    before = (self.stats["delta_updates"],
+                              self.stats["refreezes"],
+                              self.stats["delta_elems"])
+                    self._sync_device(prefer_delta=not want_refreeze)
+                    if self.stats["delta_updates"] > before[0]:
+                        device = "delta"
+                        elems = self.stats["delta_elems"] - before[2]
+                    elif self.stats["refreezes"] > before[1]:
+                        device = "refreeze"
+            return IngestReport(
+                n=int(keys.shape[0]), slot=counts["slot"],
+                chain=counts["chain"], contested=counts["contested"],
+                epoch=self.epoch, device=device,
+                device_elems=elems, seconds=time.perf_counter() - t0,
+                placement=placement,
+                abort_reasons=getattr(self, "_last_abort_reasons", ()),
+                fused_aborts=self.stats.get("fused_abort_total", 0),
+                split_commits=self.stats.get("split_commits", 0))
 
     def _roll_caps(self) -> None:
         """Advance the keycap cache to the current epoch UNCHANGED —

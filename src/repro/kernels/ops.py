@@ -76,6 +76,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import ref as _ref
 from .lookup import fused_lookup_call, lookup_kernel_call, resolve_interpret
@@ -1267,23 +1268,25 @@ def _finish_fused_host(out, out_hi, slot, found, fb, n_q, wide, queries,
     the O(#escapes) patch only when the mask is non-empty.
     ``host_views`` is a zero-arg callable so the (lazily cached) host
     copies are only built when an escape actually occurs."""
-    fb_np = np.asarray(fb)[:n_q]
-    idx = np.flatnonzero(fb_np)
-    out_np = np.asarray(out)[:n_q]
-    if wide:
-        out_np = ((np.asarray(out_hi)[:n_q].astype(np.int64) << 32)
-                  | (out_np.astype(np.int64) & 0xFFFFFFFF))
-    slot_np = np.asarray(slot)[:n_q]
-    found_np = np.asarray(found)[:n_q]
+    with TraceAnnotation("repro.engine.fetch"):
+        fb_np = np.asarray(fb)[:n_q]
+        idx = np.flatnonzero(fb_np)
+        out_np = np.asarray(out)[:n_q]
+        if wide:
+            out_np = ((np.asarray(out_hi)[:n_q].astype(np.int64) << 32)
+                      | (out_np.astype(np.int64) & 0xFFFFFFFF))
+        slot_np = np.asarray(slot)[:n_q]
+        found_np = np.asarray(found)[:n_q]
     if idx.size:
-        out_np = np.array(out_np)
-        slot_np = np.array(slot_np)
-        found_np = np.array(found_np)
-        r, res, pay = resolve_escapes_host(
-            host_views(), np.asarray(queries, np.float64)[idx])
-        out_np[idx] = pay
-        slot_np[idx] = r
-        found_np[idx] = res
+        with TraceAnnotation("repro.engine.escape_patch"):
+            out_np = np.array(out_np)
+            slot_np = np.array(slot_np)
+            found_np = np.array(found_np)
+            r, res, pay = resolve_escapes_host(
+                host_views(), np.asarray(queries, np.float64)[idx])
+            out_np[idx] = pay
+            slot_np[idx] = r
+            found_np[idx] = res
     return out_np, slot_np, found_np, int(idx.size)
 
 
@@ -1859,7 +1862,8 @@ class QueryEngine:
     def _host_views(self) -> dict:
         cached = self._host_cache
         if cached is None or cached[0] is not self.arrays:
-            cached = (self.arrays, host_fallback_views(self.arrays))
+            with TraceAnnotation("repro.engine.host_views"):
+                cached = (self.arrays, host_fallback_views(self.arrays))
             self._host_cache = cached
         return cached[1]
 
@@ -2038,20 +2042,21 @@ class QueryEngine:
         the stage that actually ran ("fused" covers both the Pallas
         kernel and the fused XLA graph — see ``self.fused_impl``).
         """
-        key_wide = self.arrays.key_wide
-        qh, ql = _split_queries(queries, key_wide)
-        n_q = qh.shape[0]
-        b = self.bucket(n_q)
-        if b == n_q:
-            qp, qlp = qh, ql
-        else:
-            qp = np.full(b, np.inf, np.float32)
-            qp[:n_q] = qh  # +inf tail keeps sorted batches sorted
-            if key_wide:
-                qlp = np.zeros(b, np.float32)
-                qlp[:n_q] = ql
+        with TraceAnnotation("repro.engine.prep"):
+            key_wide = self.arrays.key_wide
+            qh, ql = _split_queries(queries, key_wide)
+            n_q = qh.shape[0]
+            b = self.bucket(n_q)
+            if b == n_q:
+                qp, qlp = qh, ql
             else:
-                qlp = ql
+                qp = np.full(b, np.inf, np.float32)
+                qp[:n_q] = qh  # +inf tail keeps sorted batches sorted
+                if key_wide:
+                    qlp = np.zeros(b, np.float32)
+                    qlp[:n_q] = ql
+                else:
+                    qlp = ql
         q_tile = min(b, self.q_tile or auto_q_tile(b, self.arrays.n_slots,
                                                    self.w_tile))
         backend = backend or self.backend
@@ -2072,21 +2077,23 @@ class QueryEngine:
         fb_cap = int(min(b, boost * max(
             q_tile if tile_granular else 64,
             int(np.ceil(self.fb_frac * b)))))
-        qj = jnp.asarray(qp)
-        qlj = jnp.asarray(qlp)
+        with TraceAnnotation("repro.engine.put"):
+            qj = jnp.asarray(qp)
+            qlj = jnp.asarray(qlp)
         if stage == "fused":
             # fused-XLA contract: ONE lean dispatch returning the escape
             # MASK; the (rare) flagged queries are patched in
             # O(#escapes) host numpy — no device compaction, no
             # overflow/oracle escape
             a = self.arrays
-            out, out_hi, slot, found, fb = _fused_pipeline(
-                qj, qlj, a.slot_key, a.slot_key_lo, a.payload,
-                a.payload_hi, a.link_offsets, a.link_keys,
-                a.link_keys_lo, a.link_payloads, a.link_payload_hi,
-                self._rank_table, self._rank_scale,
-                trips=self._rank_trips, max_chain=a.max_chain,
-                wide=a.wide, key_wide=a.key_wide)
+            with TraceAnnotation("repro.engine.dispatch"):
+                out, out_hi, slot, found, fb = _fused_pipeline(
+                    qj, qlj, a.slot_key, a.slot_key_lo, a.payload,
+                    a.payload_hi, a.link_offsets, a.link_keys,
+                    a.link_keys_lo, a.link_payloads, a.link_payload_hi,
+                    self._rank_table, self._rank_scale,
+                    trips=self._rank_trips, max_chain=a.max_chain,
+                    wide=a.wide, key_wide=a.key_wide)
             out, slot_h, found_h, n_fb = _finish_fused_host(
                 out, out_hi, slot, found, fb, n_q, a.wide, queries,
                 self._host_views)
@@ -2094,16 +2101,20 @@ class QueryEngine:
             self.stats["fallbacks"] += n_fb
             self.stats["buckets"].add(b)
             return out, slot_h, found_h, n_fb
-        out, out_hi, slot, found, fb, overflow = self._dispatch(
-            qj, qlj, stage, q_tile, fb_cap, bool(queries_sorted), flat_w)
+        with TraceAnnotation("repro.engine.dispatch"):
+            out, out_hi, slot, found, fb, overflow = self._dispatch(
+                qj, qlj, stage, q_tile, fb_cap, bool(queries_sorted),
+                flat_w)
         if backend != "oracle" and fb_cap < b and bool(overflow):
             self.stats["oracle_escapes"] += 1
             self._cap_boost[b] = min(boost * 4, 64)  # sticky escalation
             self.last_stage = "oracle"  # the stage that actually served
-            out, out_hi, slot, found, fb, _ = self._dispatch(
-                qj, qlj, "oracle", q_tile, fb_cap, bool(queries_sorted))
+            with TraceAnnotation("repro.engine.dispatch"):
+                out, out_hi, slot, found, fb, _ = self._dispatch(
+                    qj, qlj, "oracle", q_tile, fb_cap, bool(queries_sorted))
         self.stats["calls"] += 1
         self.stats["fallbacks"] += int(fb)
         self.stats["buckets"].add(b)
-        out = _recombine_i64(out, out_hi, n_q, self.arrays.wide)
+        with TraceAnnotation("repro.engine.fetch"):
+            out = _recombine_i64(out, out_hi, n_q, self.arrays.wide)
         return out, slot[:n_q], found[:n_q], fb

@@ -47,6 +47,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.results import Overloaded
 from ..models import Model
@@ -275,7 +276,7 @@ class MicroBatchQueue:
         zero submissions has no last real key to pad the staging buffer
         with, and silently reading the previous flush's stale staging
         contents is exactly the bug this guard closes."""
-        with self._lock:
+        with self._lock, TraceAnnotation("repro.queue.flush"):
             self._cancel_deadline()
             if not self._ingests and not self._lookups:
                 raise RuntimeError(
@@ -299,23 +300,25 @@ class MicroBatchQueue:
                     self.auditor.assert_ok(self.index)
             if self._lookups:
                 pend, self._lookups = self._lookups, []
-                sizes = [k.shape[0] for _, k in pend]
-                n = int(sum(sizes))
-                bucket = self._bucket(n)
-                buf = self._stage("lookup", bucket, np.float64)
-                off = 0
-                for _, k in pend:
-                    buf[off: off + k.shape[0]] = k
-                    off += k.shape[0]
-                buf[off:] = buf[off - 1]  # pad: repeat the last real key
+                with TraceAnnotation("repro.queue.stage"):
+                    sizes = [k.shape[0] for _, k in pend]
+                    n = int(sum(sizes))
+                    bucket = self._bucket(n)
+                    buf = self._stage("lookup", bucket, np.float64)
+                    off = 0
+                    for _, k in pend:
+                        buf[off: off + k.shape[0]] = k
+                        off += k.shape[0]
+                    buf[off:] = buf[off - 1]  # pad: repeat the last real key
                 res = self.index.lookup(buf)
-                off = 0
-                for (t, k), sz in zip(pend, sizes):
-                    sl = slice(off, off + sz)
-                    self._results[t] = dataclasses.replace(
-                        res, payloads=res.payloads[sl],
-                        slots=res.slots[sl], found=res.found[sl])
-                    off += sz
+                with TraceAnnotation("repro.queue.demux"):
+                    off = 0
+                    for (t, k), sz in zip(pend, sizes):
+                        sl = slice(off, off + sz)
+                        self._results[t] = dataclasses.replace(
+                            res, payloads=res.payloads[sl],
+                            slots=res.slots[sl], found=res.found[sl])
+                        off += sz
                 self.stats["lookup_dispatches"] += 1
                 self.stats["coalesced_lookups"] += len(pend)
             self.stats["flushes"] += 1

@@ -42,6 +42,7 @@ import threading
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.results import LookupResult
 
@@ -255,17 +256,18 @@ class EpochPipeline:
         While ingest is in flight (live epoch ahead), the pinned
         snapshot serves — retained first, so a concurrent ``publish``
         releasing its reference cannot unpin it under the reader."""
-        with self._lock:
-            snap = self._snapshot
-            if int(self.index.epoch) == snap.epoch:
-                self.stats["live_lookups"] += 1
-                return self.index.lookup(queries, backend=backend)
-            self.stats["snapshot_lookups"] += 1
-            snap.retain()
-        try:
-            return snap.lookup(queries)
-        finally:
-            snap.release()
+        with TraceAnnotation("repro.pipeline.lookup"):
+            with self._lock:
+                snap = self._snapshot
+                if int(self.index.epoch) == snap.epoch:
+                    self.stats["live_lookups"] += 1
+                    return self.index.lookup(queries, backend=backend)
+                self.stats["snapshot_lookups"] += 1
+                snap.retain()
+            try:
+                return snap.lookup(queries)
+            finally:
+                snap.release()
 
     def ingest(self, keys, payloads):
         """Apply an ingest batch to the LIVE index (epoch N+1 under
@@ -277,7 +279,7 @@ class EpochPipeline:
             self.faults.check("pipeline.ingest")
         keys = np.atleast_1d(np.asarray(keys, np.float64))
         payloads = np.atleast_1d(np.asarray(payloads, np.int64))
-        with self._lock:
+        with self._lock, TraceAnnotation("repro.pipeline.ingest"):
             if self.wal is not None:
                 self.wal.append(keys, payloads)  # write-ahead: log, THEN apply
                 self.stats["wal_records"] += 1
@@ -341,7 +343,7 @@ class EpochPipeline:
         drift check.  Returns the newly served epoch."""
         if self.faults is not None:
             self.faults.check("pipeline.publish")
-        with self._lock:
+        with self._lock, TraceAnnotation("repro.pipeline.publish"):
             new = pin_index(self.index)  # fully pinned BEFORE the swap
             old, self._snapshot = self._snapshot, new
             old.release()
