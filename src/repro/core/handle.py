@@ -527,7 +527,7 @@ class Index:
         if plm is None:
             return
         from ..kernels import ops as _ops
-        # fused-path rank table: refresh only the touched buckets
+        # fused-path rank router: refresh only the touched rows
         eng.refresh_rank_rows(touched_keys, self.gapped.slot_key)
         segs = np.unique(plm.segment_of(np.asarray(touched_keys,
                                                    np.float64)))

@@ -32,8 +32,8 @@ resident device buffers current across host mutations by **epoch**:
   scatters ONLY changed elements into the device buffers.  Shapes and
   jit statics are frozen with headroom, so compiled executables survive;
 * after a delta the handle INCREMENTALLY refreshes the derived read
-  tables for just the touched key ranges: the fused path's bucket->rank
-  rows (``QueryEngine.refresh_rank_rows``) and the per-segment window
+  tables for just the touched key ranges: the fused path's key->rank
+  router rows (``QueryEngine.refresh_rank_rows``) and the per-segment window
   bounds (``query_window_bounds(segments=...)`` ->
   ``QueryEngine.refresh_bounds``) — so the compacted-fallback rate
   stays flat under churn instead of climbing until the policy refreeze.
@@ -52,9 +52,10 @@ engine name    handle name     wide   search stage
 ``fused``      fused           yes    THE default device path, one lean
                                       dispatch at every batch size, on
                                       every platform: the fused XLA
-                                      graph — one bucket->slot-rank
-                                      table collapses route+predict+
-                                      window into two gathers + a
+                                      graph — a two-level key->slot-
+                                      rank router that follows the
+                                      key CDF collapses route+predict+
+                                      window into three gathers + a
                                       ~log2(p99 occupancy) bisect;
                                       escapes return as a MASK and are
                                       patched in O(#escapes) host numpy.
@@ -110,7 +111,7 @@ stages with no host round trip between them:
    sorted chain keys, a prefix-sum shift relocates every old entry, and
    the offsets advance by a cumsum — the in-graph twin of the host
    ``CSRLinks._merge`` single-allocation merge (no ``np.insert``);
-4. **read-table refresh** — the touched bucket->rank rows recompute
+4. **read-table refresh** — the touched key->rank router rows recompute
    against the NEW slot keys and the touched segments' window bounds
    widen in-graph, so the committed engine needs no separate
    ``refresh_rank_rows``/``refresh_bounds`` upload.

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .lookup import resolve_interpret
+from .ops import rank_row
 
 
 # ---------------------------------------------------------------------------
@@ -368,8 +369,9 @@ def fused_ingest_body(
     link_offsets,                     # (O,) i32 CSR offsets (tail=total)
     link_hi, link_lo,                 # (Lpad,) chain keys (+inf padded)
     lpay_lo, lpay_hi,                 # (Lpad,) i32 chain payload pair
-    rank_table,                       # (R+1,) i32 fused-lookup rank rows
-    rank_bounds_hi, rank_bounds_lo,   # (R+1,) f32 pair of bucket bounds
+    rank_l1,                          # (R1,) i32 rank-router level 1
+    rank_table,                       # (P,) i32 fused-lookup rank rows
+    rank_bounds_hi, rank_bounds_lo,   # (P,) f32 pair of row bounds
     rank_scale,                       # (3,) f32 (kmin_hi, kmin_lo, scale)
     elo, ehi,                         # (k_pad,) f32 per-seg window bounds
     *,
@@ -410,8 +412,10 @@ def fused_ingest_body(
       elements shift by ``searchsorted(pos, i, 'right')``, offsets gain
       a prefix-sum of per-slot counts — single-allocation, no host
       ``np.insert``;
-    * refresh arm — touched bucket rows of the fused lookup's rank
-      table are re-bisected against the NEW slot keys in-graph, and the
+    * refresh arm — the rank-router rows around each inserted key (its
+      ``ops.rank_row`` row and both neighbours, the rows
+      ``QueryEngine.refresh_rank_rows`` refreshes on the host) are
+      re-bisected against the NEW slot keys in-graph, and the
       per-segment window bounds are widened by a scatter-min/max of the
       inserted keys' (slot - predict) residuals.  Both tables are
       stale-SOUND, so the f32 bound rounding here only moves the
@@ -567,21 +571,18 @@ def fused_ingest_body(
     new_ph = spay_hi.at[idx_c].set(pay_hi, mode="drop")
 
     # ---- stage 7: rank-row refresh against the NEW slot keys ----------
-    r_size = rank_table.shape[0] - 1
-    if key_wide:
-        xb = (x_hi - rank_scale[0]) + (x_lo - rank_scale[1])
-    else:
-        xb = x_hi - rank_scale[0]
-    b = jnp.clip(xb * rank_scale[2], 0.0,
-                 float(r_size - 1)).astype(jnp.int32)
-    rows = jnp.clip(jnp.concatenate([b - 1, b, b + 1]), 0, r_size)
-    rows_ok = jnp.concatenate([valid] * 3) & (rows < r_size)
+    # a key's row is below the top row, so row + 1 never reaches padding
+    row = rank_row(x_hi, x_lo, rank_l1, rank_scale, key_wide)
+    rows = jnp.clip(jnp.concatenate([row - 1, row, row + 1]), 0,
+                    rank_table.shape[0] - 1)
+    rows_ok = jnp.concatenate([valid] * 3)
     slot_trips = int(max(m_pad, 2) - 1).bit_length() + 1
     vals = _bisect_pair(new_shi, new_slo,
                         jnp.take(rank_bounds_hi, rows),
                         jnp.take(rank_bounds_lo, rows),
                         slot_trips, strict=True) + 1
-    new_rank = rank_table.at[jnp.where(rows_ok, rows, r_size + 1)].set(
+    new_rank = rank_table.at[jnp.where(rows_ok, rows,
+                                       rank_table.shape[0])].set(
         vals, mode="drop")
 
     # ---- stage 8: window-bound widening for the inserted keys ---------

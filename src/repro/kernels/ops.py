@@ -5,8 +5,9 @@
 arrays; ``batched_lookup`` / ``QueryEngine`` run the lookup.  The
 DEFAULT path, on every platform, is the fused single dispatch (backend
 ``"fused"``) served by the fused XLA graph (``_fused_pipeline``): a
-precomputed bucket->slot-rank table collapses route+predict+window into
-two gathers plus a ~log2(p99 bucket occupancy) fixed-trip bisect, the
+precomputed two-level key->slot-rank router that follows the key CDF
+(``build_rank_router``) collapses route+predict+window into three
+gathers plus a ~log2(p99 row occupancy) fixed-trip bisect, the
 epilogue is fused behind it, and the escape MASK rides home with the
 outputs for an O(#escapes) host-numpy patch.
 
@@ -556,7 +557,7 @@ def _xla_window_lookup(queries, queries_lo, seg_first_key, seg_first_key_lo,
 def _fused_pipeline(
     queries, queries_lo, slot_key, slot_key_lo, payload, payload_hi,
     link_offsets, link_keys, link_keys_lo, link_payloads, link_payload_hi,
-    rank_table, rank_scale,
+    rank_l1, rank_table, rank_scale,
     *, trips, max_chain, wide, key_wide,
 ):
     """The fused-XLA single dispatch: rank-routed bounded search + fused
@@ -572,7 +573,7 @@ def _fused_pipeline(
     """
     slot, found, fb = _fused_search(
         queries, queries_lo, slot_key, slot_key_lo,
-        rank_table, rank_scale, trips, key_wide,
+        rank_l1, rank_table, rank_scale, trips, key_wide,
     )
     out, out_hi, resolved = _epilogue(
         queries, queries_lo, slot, found, payload, payload_hi,
@@ -650,19 +651,18 @@ def _route_segment(queries, queries_lo, seg_first_key, seg_first_key_lo,
 
 
 def _fused_search(queries, queries_lo, slot_key, slot_key_lo,
-                  rank_table, rank_scale, trips, key_wide):
+                  rank_l1, rank_table, rank_scale, trips, key_wide):
     """Minimal-gather fused search: the XLA half of the fused
     single-dispatch backend (the Pallas fused kernel is the TPU half).
 
-    On CPU/GPU XLA the lookup is GATHER-bound (gathers lower to scalar
-    loops), so the whole route -> predict -> window chain is collapsed
-    into one precomputed **bucket -> slot-rank table** (the device image
-    of the mechanism's prediction, materialized at freeze time by
-    ``build_rank_router``): per query that is TWO table gathers (window
-    lower/upper rank — adjacent table rows) plus a ~log2(p99 bucket
-    occupancy) fixed-trip bisect, versus the oracle's log2(Mpad) probes
-    and the reference path's 4-gather segment routing + err-window
-    bisect.
+    The whole route -> predict -> window chain is collapsed into the
+    two-level **key -> slot-rank router** (``build_rank_router``, the
+    device image of the key CDF materialized at freeze time): per query
+    one gather of the level-1 row, one multiply for the level-2 row
+    (``rank_row``), then TWO adjacent rank gathers (window lower/upper
+    rank) plus a ~log2(p99 row occupancy) fixed-trip bisect, versus the
+    oracle's log2(Mpad) probes and the reference path's 4-gather segment
+    routing + err-window bisect.
 
     The trailing **bracket validation** (``slot_key[r] <= q <
     slot_key[r+1]``, one of whose gathers doubles as the ``found``
@@ -673,14 +673,9 @@ def _fused_search(queries, queries_lo, slot_key, slot_key_lo,
     buffer like every other backend.
     """
     m_pad = slot_key.shape[0]
-    r = rank_table.shape[0] - 1
-    if key_wide:
-        x = (queries - rank_scale[0]) + (queries_lo - rank_scale[1])
-    else:
-        x = queries - rank_scale[0]
-    b = jnp.clip(x * rank_scale[2], 0.0, float(r - 1)).astype(jnp.int32)
-    lo0 = jnp.take(rank_table, b) - 1
-    hi0 = jnp.maximum(jnp.take(rank_table, b + 1) - 1, lo0)
+    row = rank_row(queries, queries_lo, rank_l1, rank_scale, key_wide)
+    lo0 = jnp.take(rank_table, row) - 1
+    hi0 = jnp.maximum(jnp.take(rank_table, row + 1) - 1, lo0)
     if key_wide:
         slot = _pair_bisect(slot_key, slot_key_lo, queries, queries_lo,
                             lo0, hi0, trips)
@@ -741,41 +736,155 @@ def build_radix_router(arrays: "IndexArrays", r_size: int = 1 << 14):
     return table, np.array([kmin_hi[0], kmin_lo[0], scale], np.float32)
 
 
-def build_rank_router(slot_key, slot_key_lo=None, r_bits: int = 16,
-                      trips_pct: float = 99.0):
-    """Bucket -> slot-rank table for the fused XLA search.
+_L1_LG_BITS = 5  # low bits of a level-1 row: log2 of its sub-row count
 
-    ``table[b]`` is the rank (searchsorted-left) of bucket b's lower key
-    boundary in the frozen slot-key array, so a query hashing to bucket
-    b has its predecessor slot in ``[table[b] - 1, table[b+1] - 1]`` —
-    the whole route/predict/window chain becomes two gathers into one
-    (r_size + 1)-entry table.  Returns ``(table, scale, trips, meta)``:
-    ``scale`` is the f32 [kmin_hi, kmin_lo, scale] device triple,
-    ``trips`` a bisect budget covering the ``trips_pct`` percentile
-    bucket occupancy (denser buckets escape through the bracket
-    validation in ``_fused_search`` — sound, fallback-only), and
-    ``meta`` the f64 (kmin, scale, r_size) used for incremental row
-    refreshes (``QueryEngine.refresh_rank_rows``).
+
+def _rank_row(xp, queries, queries_lo, l1, scale, key_wide):
+    """Key -> level-2 row of the rank router, written once for both
+    array modules so the device graph (``rank_row``) and the host
+    refresh (``rank_row_np``) agree bit for bit: the same f32 ops in the
+    same order.  ``f = (key - kmin) * scale`` picks the level-1 bucket
+    ``floor(f)``; the bucket's ``2^lg`` equal-width sub-rows split its
+    fraction, so the sub-row is one multiply by a power of two (exact)
+    and a clip.  Keys outside [kmin, kmax] clip to the first/last row.
     """
+    f32 = np.float32
+    if key_wide:
+        x = (queries - scale[0]) + (queries_lo - scale[1])
+    else:
+        x = queries - scale[0]
+    f = x * scale[2]
+    b = xp.clip(f, f32(0), f32(l1.shape[0] - 1)).astype(np.int32)
+    v = l1[b] if xp is np else jnp.take(l1, b)
+    lg = v & ((1 << _L1_LG_BITS) - 1)
+    nsub = (xp.ones_like(lg) << lg).astype(np.float32)
+    sub = xp.clip((f - b.astype(np.float32)) * nsub, f32(0), nsub - 1)
+    return (v >> _L1_LG_BITS) + sub.astype(np.int32)
+
+
+def rank_row(queries, queries_lo, l1, scale, key_wide):
+    """Device (jnp) key -> rank-router row; see ``_rank_row``."""
+    return _rank_row(jnp, queries, queries_lo, l1, scale, key_wide)
+
+
+def rank_row_np(queries, queries_lo, l1, scale, key_wide):
+    """Host (numpy) twin of ``rank_row``, bit for bit."""
+    return _rank_row(np, queries, queries_lo, l1, scale, key_wide)
+
+
+@dataclasses.dataclass
+class RankRouter:
+    """Two-level key -> slot-rank router of the fused search (host copy).
+
+    Level 1 splits [kmin, kmax] into ``l1.shape[0]`` equal-width buckets;
+    ``l1[b]`` packs the bucket's first level-2 row (high bits) and log2
+    of its sub-row count (low ``_L1_LG_BITS`` bits).  Level 2 is one flat
+    table: ``ranks[r]`` is the searchsorted-left rank of row r's lower
+    boundary key (``bounds_hi``/``bounds_lo``, an f32 pair) in the frozen
+    slot keys.  Row ``n_rows`` is the top row and every row past it is
+    padding: their boundary is +inf, so their rank counts the finite
+    slots and a query can only escape there.  ``ranks`` is monotone in
+    key, so a query in row r has its predecessor slot in
+    ``[ranks[r] - 1, ranks[r + 1] - 1]``.
+    """
+    l1: np.ndarray          # (R1,) i32 row << _L1_LG_BITS | log2(sub-rows)
+    scale: np.ndarray       # (3,) f32 (kmin_hi, kmin_lo, R1 / (kmax - kmin))
+    ranks: np.ndarray       # (P,) i32, P >= n_rows + 1
+    bounds_hi: np.ndarray   # (P,) f32 pair of row lower boundaries
+    bounds_lo: np.ndarray
+    n_rows: int             # level-2 rows below the top row
+    trips: int              # bisect budget: p99 level-2 row occupancy
+    split: int              # level-1 buckets given more than one sub-row
+
+    def stats(self) -> dict:
+        return {"rank_rows": self.n_rows, "rank_trips": self.trips,
+                "rank_split": self.split}
+
+
+def _device_keys(slot_key, slot_key_lo=None) -> np.ndarray:
+    """f64 values of frozen device keys (pair sum when wide)."""
     sk = np.asarray(slot_key, np.float64)
-    if slot_key_lo is not None and np.asarray(slot_key_lo).size:
+    if slot_key_lo is not None and np.asarray(slot_key_lo).size > 0:
         sk = sk + np.asarray(slot_key_lo, np.float64)
+    return sk
+
+
+def _ranks_at(sk, bounds_hi, bounds_lo, key_wide: bool) -> np.ndarray:
+    """Number of sorted f64 keys ``sk`` whose frozen device
+    representation (f32 hi/lo pair when ``key_wide``, else f32) lies
+    below each f32-pair boundary: the numpy twin of the fused ingest's
+    strict pair bisect (pair order is numeric order, and a pair sum is
+    exact in f64).  Only keys within rounding distance of a boundary
+    are rounded, so the cost is O(#bounds * log n), not O(n)."""
+    bnd = bounds_hi.astype(np.float64) + bounds_lo.astype(np.float64)
+    # wider than any f32 rounding of a key near the boundary
+    tol = np.where(np.isfinite(bnd), np.abs(bnd) * 2.0 ** -20 + 2.0 ** -140,
+                   0.0)
+    lo = np.searchsorted(sk, bnd - tol, side="left")
+    hi = np.searchsorted(sk, bnd + tol, side="left")
+    while np.any(lo < hi):  # bisect the few keys rounding may move
+        open_ = lo < hi
+        mid = np.minimum((lo + hi) >> 1, sk.shape[0] - 1)
+        below = _device_keys(*_split_queries(sk[mid], key_wide)) < bnd
+        lo = np.where(open_ & below, mid + 1, lo)
+        hi = np.where(open_ & ~below, mid, hi)
+    return lo.astype(np.int32)
+
+
+def build_rank_router(slot_key, slot_key_lo=None, r_bits: int = 16,
+                      trips_pct: float = 99.0) -> RankRouter:
+    """Two-level key -> slot-rank router for the fused XLA search,
+    following the key CDF (see ``RankRouter``).
+
+    Level 1 keeps 2^r_bits equal-width buckets over [kmin, kmax]; a
+    bucket holding ``occ`` slots gets ``2^ceil(log2(max(1, occ / T)))``
+    equal-width sub-rows, ``T = ceil(n_slots / 2^r_bits)`` being the
+    occupancy uniform keys give every bucket.  Uniform keys thus keep
+    about one row per bucket, while the crowded buckets of a skewed CDF
+    (SOSD lognormal) split until their rows hold about T slots.  The
+    bisect budget covers the ``trips_pct`` percentile level-2 row
+    occupancy (denser rows escape through the bracket validation in
+    ``_fused_search`` — sound, fallback-only).  The level-2 table is
+    padded to a multiple of 2^r_bits rows so a refreeze of similar keys
+    keeps its shape (and its compiled graphs).
+    """
+    key_wide = slot_key_lo is not None and np.asarray(slot_key_lo).size > 0
+    sk = _device_keys(slot_key, slot_key_lo)
     fin = sk[np.isfinite(sk)]
     kmin = float(fin[0]) if fin.size else 0.0
     kmax = float(fin[-1]) if fin.size else kmin + 1.0
-    r_size = 1 << r_bits
-    scale = r_size / max(kmax - kmin, 1e-9)
-    bounds = kmin + np.arange(r_size + 1, dtype=np.float64) / scale
-    table = np.searchsorted(sk, bounds, side="left").astype(np.int32)
-    # top boundary: include every slot <= kmax (duplicated max keys)
-    table[-1] = np.searchsorted(sk, kmax, side="right")
-    occ = (table[1:] - table[:-1]).astype(np.float64)
-    p = float(np.percentile(occ, trips_pct)) if occ.size else 1.0
+    r1 = 1 << r_bits
+    # the f32 scale the device multiplies by, so host boundaries sit
+    # exactly where the device's bucket position crosses them
+    scale = float(np.float32(r1 / max(kmax - kmin, 1e-9)))
+    edges = kmin + np.arange(r1 + 1, dtype=np.float64) / scale
+    first = np.searchsorted(sk, edges, side="left")
+    first[-1] = fin.size
+    occ = np.diff(first)
+    per_row = max(1, -(-fin.size // r1))
+    lg = np.zeros(r1, np.int64)
+    crowded = occ > per_row
+    lg[crowded] = np.ceil(np.log2(occ[crowded] / per_row)).astype(np.int64)
+    nsub = np.left_shift(1, lg)
+    start = np.cumsum(nsub) - nsub
+    n_rows = int(nsub.sum())
+    bucket = np.repeat(np.arange(r1, dtype=np.int64), nsub)
+    sub = np.arange(n_rows, dtype=np.int64) - start[bucket]
+    pos = bucket + sub / nsub[bucket]          # exact: nsub is 2^lg
+    n_pad = -(-(n_rows + 1) // r1) * r1
+    bounds = np.full(n_pad, np.inf)
+    bounds[:n_rows] = kmin + pos / scale
+    b_hi, b_lo = split_key_pair(bounds)
+    ranks = _ranks_at(sk, b_hi, b_lo, key_wide)
+    p = float(np.percentile(np.diff(ranks[:n_rows + 1]), trips_pct))
     trips = int(max(1, np.ceil(np.log2(p + 3.0)) + 1))
     trips = min(trips, int(np.ceil(np.log2(max(sk.shape[0], 2)))) + 1)
     kmin_hi, kmin_lo = split_key_pair(np.array([kmin]))
-    return (table, np.array([kmin_hi[0], kmin_lo[0], scale], np.float32),
-            trips, (kmin, scale, r_size))
+    return RankRouter(
+        l1=((start << _L1_LG_BITS) | lg).astype(np.int32),
+        scale=np.array([kmin_hi[0], kmin_lo[0], scale], np.float32),
+        ranks=ranks, bounds_hi=b_hi, bounds_lo=b_lo, n_rows=n_rows,
+        trips=trips, split=int(np.count_nonzero(crowded)))
 
 
 def _cached_rank_router(arrays: "IndexArrays"):
@@ -786,10 +895,11 @@ def _cached_rank_router(arrays: "IndexArrays"):
     update produces a NEW instance and therefore a fresh build."""
     cached = getattr(arrays, "_rank_router_cache", None)
     if cached is None:
-        table, scale, trips, _meta = build_rank_router(
+        rt = build_rank_router(
             np.asarray(arrays.slot_key),
             np.asarray(arrays.slot_key_lo) if arrays.key_wide else None)
-        cached = (jnp.asarray(table), jnp.asarray(scale), trips)
+        cached = (jnp.asarray(rt.l1), jnp.asarray(rt.ranks),
+                  jnp.asarray(rt.scale), rt.trips)
         object.__setattr__(arrays, "_rank_router_cache", cached)
     return cached
 
@@ -1368,14 +1478,15 @@ def batched_lookup(
         # lean single dispatch + O(#escapes) host patch (see
         # _fused_pipeline); early return — none of the legacy statics
         # below apply
-        rank_table, rank_scale, rk_trips = _cached_rank_router(arrays)
+        rank_l1, rank_table, rank_scale, rk_trips = \
+            _cached_rank_router(arrays)
         out, out_hi, slot, found, fbm = _fused_pipeline(
             jnp.asarray(qp), jnp.asarray(qlp),
             arrays.slot_key, arrays.slot_key_lo,
             arrays.payload, arrays.payload_hi,
             arrays.link_offsets, arrays.link_keys, arrays.link_keys_lo,
             arrays.link_payloads, arrays.link_payload_hi,
-            rank_table, rank_scale,
+            rank_l1, rank_table, rank_scale,
             trips=rk_trips, max_chain=arrays.max_chain,
             wide=arrays.wide, key_wide=arrays.key_wide)
         return _finish_fused_host(out, out_hi, slot, found, fbm, n_q,
@@ -1788,15 +1899,14 @@ class QueryEngine:
         table, scale = build_radix_router(arrays)
         self._radix_table = jnp.asarray(table)
         self._radix_scale = jnp.asarray(scale)
-        # bucket -> slot-rank table for the fused XLA search (the
-        # host-side numpy copy feeds incremental row refreshes)
-        self._rank_np, rk_scale, self._rank_trips, self._rank_meta = \
-            build_rank_router(
-                np.asarray(arrays.slot_key),
-                np.asarray(arrays.slot_key_lo) if arrays.key_wide
-                else None)
-        self._rank_table = jnp.asarray(self._rank_np)
-        self._rank_scale = jnp.asarray(rk_scale)
+        # two-level key -> slot-rank router for the fused XLA search
+        # (the host copy feeds incremental row refreshes)
+        self._router = build_rank_router(
+            np.asarray(arrays.slot_key),
+            np.asarray(arrays.slot_key_lo) if arrays.key_wide else None)
+        self._rank_l1 = jnp.asarray(self._router.l1)
+        self._rank_table = jnp.asarray(self._router.ranks)
+        self._rank_scale = jnp.asarray(self._router.scale)
         # sticky per-bucket fallback-capacity boost: a workload that once
         # overflowed gets a larger compaction buffer next time instead of
         # paying the oracle escape on every call
@@ -1805,8 +1915,10 @@ class QueryEngine:
         # whenever swap_arrays installs delta-updated buffers)
         self._host_cache = None
         self.last_stage: Optional[str] = None  # search stage of last call
+        # the router's shape, recorded at build so a test or a traced
+        # run can tell which table served
         self.stats = {"calls": 0, "fallbacks": 0, "oracle_escapes": 0,
-                      "buckets": set()}
+                      "buckets": set(), **self._router.stats()}
 
     @classmethod
     def from_index(cls, index, *, w_tile: int = 2048, seg_chunk: int = 512,
@@ -1870,20 +1982,22 @@ class QueryEngine:
     def refresh_rank_rows(self, touched_keys, slot_key, slot_key_lo=None,
                           upload=True):
         """Incrementally refresh the fused path's rank table after a
-        delta update: only the buckets covering the touched key values
-        recompute their boundary ranks against the CURRENT (host) slot
-        keys.  A skipped/stale row is sound — the fused search's bracket
-        validation turns it into compacted fallbacks, never wrong
-        results — so this is purely a fallback-rate knob.
+        delta update: only the level-2 rows around the touched keys
+        (each key's row and its two neighbours) recompute their
+        boundary ranks against the CURRENT (host) slot keys.  A
+        skipped/stale row is sound — the fused search's bracket
+        validation turns it into fallbacks, never wrong results — so
+        this is purely a fallback-rate knob.
 
-        ``upload=False`` refreshes only the host copy (``_rank_np``):
-        the fused single-dispatch ingest already wrote the refreshed
-        rows into the device table in-graph, so the commit path only
-        needs the host mirror caught up for FUTURE incremental calls.
+        ``upload=False`` refreshes only the host copy: the fused
+        single-dispatch ingest already wrote the same rows into the
+        device table in-graph (``gap_place.fused_ingest_body`` stage
+        7), so the commit path only needs the host mirror caught up for
+        FUTURE incremental calls.
         """
         touched = np.asarray(touched_keys, np.float64)
-        kmin, scale, r_size = self._rank_meta
-        if touched.size == 0 or touched.size > r_size // 4:
+        rt = self._router
+        if touched.size == 0 or touched.size > rt.n_rows // 4:
             # empty, or near-global churn: a row-by-row refresh would
             # cost more than the fallbacks it saves — stale rows stay
             # sound (bracket validation), and the refreeze policy
@@ -1892,34 +2006,21 @@ class QueryEngine:
         touched = touched[np.isfinite(touched)]
         if touched.size == 0:
             return
-        b = np.clip((touched - kmin) * scale, 0, r_size - 1).astype(np.int64)
-        # one row of margin each side: the representation rounding below
-        # can move a key across a bucket boundary
-        rows = np.unique(np.clip(np.concatenate([b - 1, b, b + 1]),
-                                 0, r_size))
-        sk = np.asarray(slot_key, np.float64)
-        if slot_key_lo is not None and np.asarray(slot_key_lo).size:
-            sk = sk + np.asarray(slot_key_lo, np.float64)
-        # round into the FROZEN device key representation (f32 hi/lo
-        # pair sum when wide, plain f32 when narrow) so the refreshed
-        # ranks agree with the device bracket validation bit-for-bit —
-        # the table was built from the device values, and callers pass
+        key_wide = self.arrays.key_wide
+        qh, ql = _split_queries(touched, key_wide)
+        row = rank_row_np(qh, ql, rt.l1, rt.scale, key_wide)
+        # and both neighbours: the f32 row of a key near a boundary may
+        # be the row beside the one whose boundary its slot crossed
+        rows = np.unique(np.clip(np.concatenate([row - 1, row, row + 1]),
+                                 0, rt.n_rows))
+        # ranks in the FROZEN device key representation, so they agree
+        # with the device bracket validation bit-for-bit — callers pass
         # the full-precision host keys
-        if self.arrays.key_wide:
-            sk_hi, sk_lo = split_key_pair(sk)
-            sk = sk_hi.astype(np.float64) + sk_lo.astype(np.float64)
-        else:
-            sk = sk.astype(np.float32).astype(np.float64)
-        bounds = kmin + rows.astype(np.float64) / scale
-        vals = np.searchsorted(sk, bounds, side="left").astype(np.int32)
-        top = rows == r_size
-        if np.any(top):  # top boundary includes duplicated max keys
-            fin = sk[np.isfinite(sk)]
-            kmax = float(fin[-1]) if fin.size else kmin
-            vals[top] = np.searchsorted(sk, kmax, side="right")
-        self._rank_np[rows] = vals
+        rt.ranks[rows] = _ranks_at(_device_keys(slot_key, slot_key_lo),
+                                   rt.bounds_hi[rows], rt.bounds_lo[rows],
+                                   key_wide)
         if upload:
-            self._rank_table = jnp.asarray(self._rank_np)
+            self._rank_table = jnp.asarray(rt.ranks)
 
     def ingest_place(self, keys):
         """Device §5.3 ingest placement against the frozen arrays: the
@@ -1935,17 +2036,14 @@ class QueryEngine:
                       interpret=self.interpret)
 
     def _rank_bounds(self):
-        """Device-resident f32-pair bucket-boundary keys for the fused
-        ingest graph's in-dispatch rank-row refresh.  Lazy (~2x(r+1)
-        f32, built once per engine): lookups never touch it, and rebuild
-        is only needed on refreeze — which makes a new engine anyway."""
+        """Device-resident f32-pair row-boundary keys for the fused
+        ingest graph's in-dispatch rank-row refresh.  Lazy (built once
+        per engine): lookups never touch them, and rebuild is only
+        needed on refreeze — which makes a new engine anyway."""
         cached = getattr(self, "_rank_bounds_pair", None)
         if cached is None:
-            kmin, scale, r_size = self._rank_meta
-            bounds = kmin + np.arange(int(r_size) + 1,
-                                      dtype=np.float64) / scale
-            bh, bl = split_key_pair(bounds)
-            cached = (jnp.asarray(bh), jnp.asarray(bl))
+            cached = (jnp.asarray(self._router.bounds_hi),
+                      jnp.asarray(self._router.bounds_lo))
             self._rank_bounds_pair = cached
         return cached
 
@@ -1962,7 +2060,8 @@ class QueryEngine:
         from .ops_gap import fused_ingest as _fused
         bh, bl = self._rank_bounds()
         return _fused(
-            self.arrays, keys, payloads, rank_table=self._rank_table,
+            self.arrays, keys, payloads, rank_l1=self._rank_l1,
+            rank_table=self._rank_table,
             rank_bounds_hi=bh, rank_bounds_lo=bl,
             rank_scale=self._rank_scale, elo=self._elo, ehi=self._ehi,
             max_chain=self.arrays.max_chain, impl=self.fused_impl,
@@ -2091,8 +2190,8 @@ class QueryEngine:
                     qj, qlj, a.slot_key, a.slot_key_lo, a.payload,
                     a.payload_hi, a.link_offsets, a.link_keys,
                     a.link_keys_lo, a.link_payloads, a.link_payload_hi,
-                    self._rank_table, self._rank_scale,
-                    trips=self._rank_trips, max_chain=a.max_chain,
+                    self._rank_l1, self._rank_table, self._rank_scale,
+                    trips=self._router.trips, max_chain=a.max_chain,
                     wide=a.wide, key_wide=a.key_wide)
             out, slot_h, found_h, n_fb = _finish_fused_host(
                 out, out_hi, slot, found, fb, n_q, a.wide, queries,

@@ -192,23 +192,24 @@ FUSED_ABORT_BITS = (
 def _fused_ingest_xla(
         x_hi, x_lo, pay_lo, pay_hi, segk_hi, segk_lo, slope_hi, slope_lo,
         icept_hi, icept_lo, slot_hi, slot_lo, spay_lo, spay_hi,
-        link_offsets, link_hi, link_lo, lpay_lo, lpay_hi, rank_table,
-        rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi, *,
-        n_slots, max_chain, key_wide, use_pallas, interpret, key_tile):
+        link_offsets, link_hi, link_lo, lpay_lo, lpay_hi, rank_l1,
+        rank_table, rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi,
+        *, n_slots, max_chain, key_wide, use_pallas, interpret, key_tile):
     """The one device dispatch ``Index.ingest`` issues on the fused
     path (the dispatch-counting shim in tests/test_fused_ingest.py
     monkeypatches exactly this symbol)."""
     return fused_ingest_body(
         x_hi, x_lo, pay_lo, pay_hi, segk_hi, segk_lo, slope_hi, slope_lo,
         icept_hi, icept_lo, slot_hi, slot_lo, spay_lo, spay_hi,
-        link_offsets, link_hi, link_lo, lpay_lo, lpay_hi, rank_table,
-        rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi,
+        link_offsets, link_hi, link_lo, lpay_lo, lpay_hi, rank_l1,
+        rank_table, rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi,
         n_slots=n_slots, max_chain=max_chain, key_wide=key_wide,
         use_pallas=use_pallas, interpret=interpret, key_tile=key_tile)
 
 
-def fused_ingest(arrays, keys, payloads, *, rank_table, rank_bounds_hi,
-                 rank_bounds_lo, rank_scale, elo, ehi, max_chain,
+def fused_ingest(arrays, keys, payloads, *, rank_l1, rank_table,
+                 rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi,
+                 max_chain,
                  impl: str = "xla", interpret: Optional[bool] = None,
                  min_bucket: int = 256, key_tile: int = 512):
     """Single-dispatch device-resident ingest.
@@ -260,8 +261,8 @@ def fused_ingest(arrays, keys, payloads, *, rank_table, rank_bounds_hi,
         arrays.seg_slope, arrays.seg_slope_lo, arrays.seg_icept,
         arrays.seg_icept_lo, arrays.slot_key, slot_lo, arrays.payload,
         spay_hi, arrays.link_offsets, arrays.link_keys, link_lo,
-        arrays.link_payloads, lpay_hi, rank_table, rank_bounds_hi,
-        rank_bounds_lo, rank_scale, elo, ehi,
+        arrays.link_payloads, lpay_hi, rank_l1, rank_table,
+        rank_bounds_hi, rank_bounds_lo, rank_scale, elo, ehi,
         n_slots=arrays.n_slots, max_chain=int(max_chain),
         key_wide=key_wide, use_pallas=(impl == "pallas"),
         interpret=resolve_interpret(interpret), key_tile=key_tile)

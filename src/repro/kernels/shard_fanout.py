@@ -120,15 +120,17 @@ def stack_shard_images(shards, *, w_tile: int = 2048):
                             if wide else np.zeros((len(imgs), 0),
                                                   np.int32)),
     }
-    tables, scales, trips = [], [], 1
-    for a, st in imgs:
-        tbl, scl, tr, _meta = _ops.build_rank_router(
-            a["slot_key"], a["slot_key_lo"] if st["key_wide"] else None)
-        tables.append(tbl)
-        scales.append(scl)
-        trips = max(trips, tr)
-    stacked["rank_table"] = np.stack(tables)
-    stacked["rank_scale"] = np.stack(scales)
+    routers = [_ops.build_rank_router(
+        a["slot_key"], a["slot_key_lo"] if st["key_wide"] else None)
+        for a, st in imgs]
+    # level-2 tables padded to the largest shard's rows: a padding row
+    # repeats the top rank, so it can only produce escapes
+    r_pad = max(rt.ranks.shape[0] for rt in routers)
+    stacked["rank_l1"] = np.stack([rt.l1 for rt in routers])
+    stacked["rank_table"] = np.stack([
+        _pad_to(rt.ranks, r_pad, rt.ranks[-1]) for rt in routers])
+    stacked["rank_scale"] = np.stack([rt.scale for rt in routers])
+    trips = max(rt.trips for rt in routers)
     statics = {
         "n_shards": len(imgs),
         "trips": trips,
@@ -357,9 +359,9 @@ class ShardFanout:
                                      st["key_wide"])
 
         def one_shard(q, ql, sk, skl, pay, payh, off, lk, lkl, lp, lph,
-                      tbl, scl):
+                      l1, tbl, scl):
             slot, found, fb = _ops._fused_search(
-                q, ql, sk, skl, tbl, scl, trips, key_wide)
+                q, ql, sk, skl, l1, tbl, scl, trips, key_wide)
             out, out_hi, resolved = _ops._epilogue(
                 q, ql, slot, found, pay, payh, off, lk, lkl, lp, lph,
                 max_chain, wide, key_wide)
@@ -392,7 +394,7 @@ class ShardFanout:
                 arrs["payload"], arrs["payload_hi"], arrs["link_offsets"],
                 arrs["link_keys"], arrs["link_keys_lo"],
                 arrs["link_payloads"], arrs["link_payload_hi"],
-                arrs["rank_table"], arrs["rank_scale"])
+                arrs["rank_l1"], arrs["rank_table"], arrs["rank_scale"])
 
             def exch_back(vals):
                 send = vals.reshape(s_loc, D, cap).transpose(

@@ -26,6 +26,8 @@ def make_keys(kind: str, n: int, seed: int = 0) -> np.ndarray:
         return np.unique(np.concatenate([a, b]))
     if kind == "uniform_int":  # f32-exact integer grid
         return np.unique(rng.choice(2 ** 22, n, replace=False)).astype(np.float64)
+    if kind == "lognormal":  # SOSD lognormal: floor(1e9 * X), X ~ LN(0, 2)
+        return np.unique(np.floor(1e9 * rng.lognormal(0.0, 2.0, n)))
     raise KeyError(kind)
 
 

@@ -135,6 +135,49 @@ def test_fused_then_scalar_then_delta_roundtrip():
                           idx.gapped.lookup_batch(probe))
 
 
+def test_fused_ingest_rank_rows_match_host_refresh():
+    """On skewed (SOSD lognormal, wide) keys the rank-router rows the
+    fused ingest graph rewrites in-graph equal the rows the host
+    ``refresh_rank_rows`` computes for the same batch, and the jnp and
+    numpy key -> row helpers agree bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from conftest import make_keys
+    from repro.kernels import ops
+
+    keys = make_keys("lognormal", 25_000, seed=4)
+    idx = Index.build(keys, method="pgm", eps=64, gap_rho=0.2)
+    idx.fused_ingest_enabled = True
+    idx.sync_device()
+    eng = idx._engine
+    rt = eng._router
+    assert eng.arrays.key_wide and rt.split > 0
+    before = rt.ranks.copy()
+    batch = _spread(keys, 1_000)
+    rep = idx.ingest(batch, 3_000_000 + np.arange(batch.size))
+    assert rep.device == "fused"
+    device_rows = np.asarray(eng._rank_table)   # written in stage 7
+    assert eng._router is rt
+    assert not np.array_equal(device_rows, before)
+    assert np.array_equal(device_rows, rt.ranks)  # host refresh, same rows
+
+    rng = np.random.default_rng(4)
+    probe = np.concatenate([
+        rng.choice(keys, 2_000), batch, keys[[0, -1]],
+        [keys[0] - 7.0, keys[-1] * 2.0, np.inf]])
+    qh, ql = ops.split_key_pair(probe)
+    host = ops.rank_row_np(qh, ql, rt.l1, rt.scale, True)
+    dev = jax.jit(ops.rank_row, static_argnums=4)(
+        jnp.asarray(qh), jnp.asarray(ql), jnp.asarray(rt.l1),
+        jnp.asarray(rt.scale), True)
+    assert np.array_equal(np.asarray(dev), host)
+    assert host.min() >= 0 and host.max() < rt.n_rows
+    res = idx.lookup(probe[:-1])
+    assert np.array_equal(np.asarray(res.payloads),
+                          idx.gapped.lookup_batch(probe[:-1]))
+
+
 # ---------------------------------------------------------------------------
 # aborted batch: in-graph refusal, primitives reused, state identical
 # ---------------------------------------------------------------------------

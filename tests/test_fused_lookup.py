@@ -90,9 +90,10 @@ def test_fused_wide_payloads_roundtrip():
 
 
 def test_fused_escape_patch_is_exact():
-    """A poisoned rank table (every window 1 slot wide) flags nearly
-    every query; the O(#escapes) host patch must still produce
-    oracle-exact results — the fused path's stale-table soundness."""
+    """A poisoned level-2 rank table (every row above the middle one
+    empty) flags nearly every query; the O(#escapes) host patch must
+    still produce oracle-exact results — the fused path's stale-table
+    soundness."""
     keys = make_keys("uniform_int", 10_000, seed=7)
     idx = LearnedIndex.build(keys, method="pgm", eps=64, gap_rho=0.2)
     eng = QueryEngine.from_index(idx)
@@ -100,7 +101,8 @@ def test_fused_escape_patch_is_exact():
     q = _mixed_queries(rng, keys, n_hit=2000, n_miss=300)
     truth = idx.gapped.lookup_batch(q)
     import jax.numpy as jnp
-    poisoned = np.minimum(eng._rank_np, eng._rank_np[len(eng._rank_np)//2])
+    ranks = eng._router.ranks
+    poisoned = np.minimum(ranks, ranks[eng._router.n_rows // 2])
     eng._rank_table = jnp.asarray(np.sort(poisoned))
     out, slot, found, fb = eng.lookup(q)
     assert fb > len(q) // 4          # the storm actually happened
@@ -237,3 +239,71 @@ def test_tpu_defaults_serve_the_compiled_xla_graph(monkeypatch):
     assert rep.device != "fused"  # the fused write graph stayed off
     res = idx.lookup(mids)
     assert res.backend == "fused" and res.found.all()
+
+
+def _single_level_trips(slot_key, slot_key_lo, r_bits=16, pct=99.0):
+    """The bisect budget of a one-level router: 2^r_bits equal-width
+    buckets, trips from the p99 occupancy over buckets."""
+    sk = np.asarray(slot_key, np.float64)
+    if slot_key_lo is not None and np.asarray(slot_key_lo).size:
+        sk = sk + np.asarray(slot_key_lo, np.float64)
+    fin = sk[np.isfinite(sk)]
+    r = 1 << r_bits
+    scale = r / max(fin[-1] - fin[0], 1e-9)
+    table = np.searchsorted(sk, fin[0] + np.arange(r + 1) / scale)
+    table[-1] = fin.size
+    p = float(np.percentile(np.diff(table), pct))
+    trips = int(max(1, np.ceil(np.log2(p + 3.0)) + 1))
+    return min(trips, int(np.ceil(np.log2(sk.shape[0]))) + 1)
+
+
+@pytest.mark.parametrize("kind", ["uniform_int", "lognormal"])
+def test_rank_router_follows_the_key_cdf(kind):
+    """The two-level rank router keeps skewed (SOSD lognormal) keys on
+    the device: a half-present batch is answered exactly with under 1%
+    escapes, and on uniform keys the router costs no more bisect trips
+    than one level of equal-width buckets did."""
+    keys = make_keys(kind, 1 << 18, seed=11)
+    idx = Index.build(keys, method="pgm", gap_rho=0.15, sample_rate=0.01)
+    idx.sync_device()
+    eng = idx._engine
+    rng = np.random.default_rng(11)
+    absent = np.setdiff1d(rng.choice(keys, 6000) + 1.0, keys)[:4096]
+    q = rng.permutation(np.concatenate([rng.choice(keys, 4096), absent]))
+    res = idx.lookup(q)
+    assert eng.last_stage == "fused"
+    assert np.array_equal(np.asarray(res.payloads),
+                          idx.gapped.lookup_batch(q))
+    assert res.fallbacks < 0.01 * q.size, res.fallbacks
+    st = eng.stats
+    assert st["rank_trips"] == eng._router.trips
+    assert st["rank_rows"] == eng._router.n_rows >= 1 << 16
+    a = eng.arrays
+    if kind == "uniform_int":
+        assert st["rank_trips"] <= _single_level_trips(
+            a.slot_key, a.slot_key_lo if a.key_wide else None)
+    else:
+        assert a.key_wide and st["rank_split"] > 0
+
+
+@pytest.mark.parametrize("key_wide", [False, True], ids=["narrow", "wide"])
+def test_rank_rows_count_keys_in_device_representation(key_wide):
+    """The host rank refresh counts full-precision keys by their frozen
+    device representation (f32, or the f32 hi/lo pair) without rounding
+    the whole array: equal to searchsorted over the rounded keys, on
+    continuous keys (f32 rounding moves narrow ones across boundaries)."""
+    rng = np.random.default_rng(12)
+    k = rng.uniform(0.0, 1e12 if key_wide else 1e3, 40_000)
+    k = np.sort(np.concatenate([k, k[:4_000] * (1 + 1e-9),
+                                np.full(64, np.inf)]))
+    hi, lo = ops_mod.split_key_pair(k)
+    rounded = (hi.astype(np.float64)
+               + (lo.astype(np.float64) if key_wide else 0.0))
+    b = np.concatenate([rng.choice(k[:-64], 2_000),
+                        rng.uniform(0.0, k[-65], 2_000), [np.inf]])
+    bh, bl = ops_mod.split_key_pair(b)
+    want = np.searchsorted(rounded, bh.astype(np.float64) + bl)
+    got = ops_mod._ranks_at(k, bh, bl, key_wide)
+    assert np.array_equal(got, want)
+    if not key_wide:  # f32 rounding moved keys across some boundary
+        assert not np.array_equal(np.searchsorted(k, b), want)

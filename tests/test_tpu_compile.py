@@ -32,7 +32,8 @@ M_PAD = N_SLOTS + W_TILE  # _freeze_numpy: padded slots + one +inf block
 N_LINKS = 1 << 16
 K_PAD = 1 << 12
 BUCKET = 8192
-RANK_ROWS = (1 << 16) + 1  # build_rank_router's default r_bits
+RANK_L1 = 1 << 16  # build_rank_router's default r_bits
+RANK_ROWS = 2 * RANK_L1  # level-2 rows, padded to a multiple of RANK_L1
 TRIPS = 12
 MAX_CHAIN = 8
 
@@ -94,6 +95,7 @@ def test_fused_lookup_graph_compiles_for_v5e(one_chip, key_wide):
         _spec((lo_l,), f32, one_chip),               # link_keys_lo
         _spec((N_LINKS,), i32, one_chip),            # link_payloads
         _spec((hi_l,), i32, one_chip),               # link_payload_hi
+        _spec((RANK_L1,), i32, one_chip),            # rank_l1
         _spec((RANK_ROWS,), i32, one_chip),          # rank_table
         _spec((3,), f32, one_chip),                  # rank_scale
     ]
@@ -139,6 +141,7 @@ def test_shard_fanout_graph_compiles_on_a_2x2_mesh(topo):
         "link_keys_lo": ((S, n_links), jnp.float32),
         "link_payloads": ((S, n_links), jnp.int32),
         "link_payload_hi": ((S, 0), jnp.int32),
+        "rank_l1": ((S, RANK_L1), jnp.int32),
         "rank_table": ((S, RANK_ROWS), jnp.int32),
         "rank_scale": ((S, 3), jnp.float32),
     }

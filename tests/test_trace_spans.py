@@ -53,18 +53,23 @@ def _parent(spans, child, name):
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """Lognormal keys (most lookups escape the rank table, so the host
-    escape patch runs) served through the queue: a lookup, an ingest
-    with a delta sync, lookups after it, and a refreeze, all traced.
-    Records each lookup call's rebuild of the escape patch's host copy."""
+    """Lognormal keys with a run of 2000 consecutive integers, narrower
+    than one row of the rank router, so the run's row holds far more
+    slots than the bisect budget: lookups of the run escape and the
+    host escape patch runs.  Served through the queue: a lookup, an
+    ingest with a delta sync, lookups after it, and a refreeze, all
+    traced.  Records each lookup call's rebuild of the escape patch's
+    host copy."""
     rng = np.random.default_rng(0)
     keys = np.unique(np.floor(1e9 * rng.lognormal(0.0, 2.0, 24_000)))
+    run = keys[keys.size // 2] + np.arange(1.0, 2_001.0)
+    keys = np.union1d(keys, run)
     new = np.setdiff1d(np.unique(np.floor(
         1e9 * rng.lognormal(0.0, 2.0, 2_000))), keys)[:1_024]
     idx = Index.build(keys, method="pgm", eps=64, gap_rho=0.15)
     idx.sync_device()
     q = MicroBatchQueue(EpochPipeline(idx, publish_every=1))
-    probe = rng.choice(keys, 600)
+    probe = rng.choice(run, 600)
     rebuilds, reps = 0, []
     log_dir = str(tmp_path_factory.mktemp("trace"))
     with jax.profiler.trace(log_dir):
