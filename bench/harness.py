@@ -9,6 +9,12 @@ window has closed and the program's state is freed, every answer is
 compared with the plain reference, and the metrics the cell lists are
 read by their readers (``bench/metrics/<name>.py``).
 
+A configuration whose ``build`` names ``shards`` builds a
+range-partitioned ``ShardedIndex``; its device image is the shard
+fan-out (``sync_fanout``), built in set-up, and every warm-up lookup
+must reach it.  A run uses the first ``chips`` devices of its cell and
+reports only those.
+
 Standard output holds one JSON record per line; the last is the result.
 The numbers compared, each with its limit, are also the last lines of
 standard error.
@@ -186,7 +192,9 @@ class Cell:
             raise BenchError(f"unknown fault {fault!r}")
         self.log = log
         self.cell, cfg, traffic = find_cell(spec, workload, root)
+        self.chips = int(self.cell["chips"])
         self.cfg = merge(cfg, (overrides or {}).get("config"))
+        self.sharded = "shards" in self.cfg["build"]
         self.traffic = merge(traffic, (overrides or {}).get("traffic"))
         self.root = root
         self.fault = fault
@@ -240,14 +248,12 @@ class Cell:
         self.index = Index.build(self.keys_sorted, payloads=self.pays_sorted,
                                  rng=self.rng_build, **self.cfg["build"])
         t1 = time.perf_counter()
-        engine = self.index.sync_device()
+        engine = (sync_fanout(self.index, self.chips) if self.sharded
+                  else self.index.sync_device())
         t2 = time.perf_counter()
         self.build_s, self.freeze_s = t2 - t0, t2 - t1
-        self.learn_s = float(self.index.learn_seconds)
-        plm = self.index.mech.plm
-        # the index's declared error bound: the PGM mechanism's eps
-        self.err_bound = float(getattr(self.index.mech, "eps", None)
-                               or plm.max_abs_error())
+        facts = (self._sharded_facts(engine) if self.sharded
+                 else self._single_facts(engine))
         self.pipe = EpochPipeline(self.index, **self.cfg.get("pipeline", {}))
         control = None
         if self.fault == "control_f32":
@@ -258,12 +264,30 @@ class Cell:
         self.probe.queue = self.queue
         self.log({"phase": "build", "build_s": self.build_s,
                   "host_build_s": t1 - t0, "freeze_s": self.freeze_s,
-                  "learn_s": self.learn_s,
-                  "n_slots": int(self.index.gapped.n_slots),
-                  "segments": int(plm.n_segments),
-                  "max_abs_error": self.err_bound,
-                  "key_wide": bool(engine.arrays.key_wide),
-                  "fused_impl": engine.fused_impl})
+                  "learn_s": self.learn_s, **facts})
+
+    def _single_facts(self, engine) -> dict:
+        self.learn_s = float(self.index.learn_seconds)
+        self.err_bound = err_bound(self.index.mech)
+        return {"n_slots": int(self.index.gapped.n_slots),
+                "segments": int(self.index.mech.plm.n_segments),
+                "max_abs_error": self.err_bound,
+                "key_wide": bool(engine.arrays.key_wide),
+                "fused_impl": engine.fused_impl}
+
+    def _sharded_facts(self, fan) -> dict:
+        shards = self.index.shards
+        self.learn_s = float(sum(sh.learn_seconds for sh in shards))
+        # the roofline's bound holds for every shard: the largest
+        self.err_bound = max(err_bound(sh.mech) for sh in shards)
+        slots = [int(sh.gapped.n_slots) for sh in shards]
+        return {"n_slots": sum(slots),
+                "segments": sum(int(sh.mech.plm.n_segments)
+                                for sh in shards),
+                "max_abs_error": self.err_bound,
+                "key_wide": bool(fan.statics["key_wide"]),
+                "fused_impl": "fanout", "n_slots_per_shard": slots,
+                "fanout_shards": fan.S, "fanout_devices": fan.D}
 
     # -- traffic ----------------------------------------------------------
     def prepare(self):
@@ -324,19 +348,44 @@ class Cell:
         rng = np.random.default_rng(0)
         t0 = time.perf_counter()
         mark = self.clock.mark()
+        before = self._fanout_lookups()
         for b in tr.get("warm_buckets", []):
             for _ in range(2):
                 keys = self.keys_sorted[rng.integers(
                     0, self.keys_sorted.size, b)]
                 q.result(q.submit_lookup(keys))
-        engine = self.index.sync_device()
-        engine._host_views()   # the escape patch's host copy, built lazily
+        if self.sharded:
+            self._warm_fanout(before)
+        else:
+            # the escape patch's host copy, built lazily
+            self.index.sync_device()._host_views()
         if self.loader is not None:
             for _ in range(int(tr["load"].get("warm_batches", 1))):
                 self._send_batch()
         self.log({"phase": "warm", "warm_s": time.perf_counter() - t0,
                   "buckets": tr.get("warm_buckets", []),
                   **self.clock.since(mark)})
+
+    def _fanout_lookups(self):
+        return self.index.stats["fanout_lookups"] if self.sharded else None
+
+    def _warm_fanout(self, before: int) -> None:
+        """The fan-out built in set-up still serves (no shard changed),
+        each shard's host copy for the escape patch is built now rather
+        than by the window's first escape, and every warm-up lookup of a
+        bucket the fan-out takes went through it: a cell whose warm-up
+        took the host route would measure the host."""
+        fan = sync_fanout(self.index, self.chips)
+        for s in range(fan.S):
+            fan._shard_host_views(s)
+        if self.fault == "control_f32":
+            return   # the control answers in the program's place
+        due = 2 * sum(1 for b in self.traffic.get("warm_buckets", [])
+                      if b >= self.index.min_device_batch)
+        moved = self._fanout_lookups() - before
+        if due == 0 or moved != due:
+            raise BenchError(f"{moved} of {due} warm-up lookups reached the "
+                             "shard fan-out")
 
     def _send_batch(self):
         if not self.loader.send(self.queue):
@@ -355,6 +404,7 @@ class Cell:
         base = q.stats["coalesced_lookups"]
         n_look, n_ing = len(probe.lookups), len(probe.ingests)
         n_batches = len(self.loader.batches) if self.loader else 0
+        fan0 = self._fanout_lookups()
         if trace_dir is not None:
             probe.span = trace_span
             annotate_flush(q)
@@ -371,9 +421,9 @@ class Cell:
             threads.append(gen.start(closed.run, q, t0, t1))
         if self.loader is not None:
             threads.append(gen.start(self.loader.run, q, t0, t1))
-        traced = None
+        traced = planes = None
         if trace_dir is not None:
-            traced = self._trace(trace_dir, t0, seconds)
+            traced, planes = self._trace(trace_dir, t0, seconds)
         for th in threads:
             th.join()
         deadline = max(time.perf_counter(), t1) + ANSWER_WAIT_S
@@ -393,10 +443,14 @@ class Cell:
                "batches": (self.loader.batches[n_batches:]
                            if self.loader else []),
                "compiles": self.clock.since(mark), "profile": traced,
-               "gc": gc_pauses.summary()}
+               "planes": planes, "gc": gc_pauses.summary()}
+        if fan0 is not None:
+            out["fanout_lookups"] = self._fanout_lookups() - fan0
         return out
 
     def _trace(self, trace_dir, t0, seconds):
+        """Profile a stretch of the window: the profile as the cell's chips
+        read it, and every plane's lines with their event counts."""
         import jax
 
         lead = min(1.0, seconds / 4)
@@ -411,7 +465,11 @@ class Cell:
                 time.sleep(span)
         finally:
             jax.profiler.stop_trace()
-        return trace_mod.load_profile(str(trace_dir))
+        raw = trace_mod.load_profile(str(trace_dir))
+        planes = [[p["name"], [[ln["name"], len(ln["events"])]
+                               for ln in p["lines"]]] for p in raw["planes"]]
+        return (trace_mod.keep_devices(
+            raw, [d.id for d in cell_devices(self.chips)]), planes)
 
     def mark_acked(self, ref: Reference, batches) -> None:
         for a, n, ts, ta, rep in batches:
@@ -437,6 +495,35 @@ class Cell:
         self.probe.pipe = None
         del self.queue, self.pipe, self.index
         gc.collect()
+
+
+def err_bound(mech) -> float:
+    """An index's declared error bound: the PGM mechanism's eps."""
+    return float(getattr(mech, "eps", None) or mech.plm.max_abs_error())
+
+
+def cell_devices(chips: int) -> list:
+    """The devices a run of a ``chips``-chip cell uses and reports."""
+    import jax
+
+    return jax.devices()[:chips]
+
+
+def sync_fanout(index, chips: int):
+    """The sharded index's device image, built now: its shard fan-out
+    (the shard images stacked and placed over the mesh), the object its
+    large lookups serve from, returned again while no shard changes.
+    Raises where the fan-out cannot serve the shard set, or where its
+    mesh reaches past the cell's devices: never the host route."""
+    fan = index._fanout()
+    if fan is None:
+        raise BenchError("the shard fan-out cannot serve this shard set")
+    outside = set(fan.mesh.devices.flat) - set(cell_devices(chips))
+    if outside:
+        raise BenchError(f"the shard fan-out's mesh uses devices "
+                         f"{sorted(d.id for d in outside)}, beyond the "
+                         f"cell's {chips} chips")
+    return fan
 
 
 # ---------------------------------------------------------------------------
@@ -598,6 +685,8 @@ def window_summary(w: dict, run: Run) -> dict:
     rec["long_calls_s_ms_rows"] = [
         [round(c[0] - run.window[0], 3), round(1e3 * (c[1] - c[0]), 1), c[3]]
         for c in calls if c[1] - c[0] > 0.02][:50]
+    if "fanout_lookups" in w:
+        rec["fanout_lookups"] = w["fanout_lookups"]
     if w["bulk"] is not None:
         rec["bulk_batches"] = len(w["bulk"].sent)
     if w["closed"] is not None:
@@ -630,8 +719,6 @@ def window_summary(w: dict, run: Run) -> dict:
 def run_cell(root, spec, workload, seed, seconds, trace, *, t_start=None,
              fault=None, overrides=None, log=emit) -> dict:
     """Build, warm, measure one window, check; returns the result."""
-    import jax
-
     t_start = time.perf_counter() if t_start is None else t_start
     cell = Cell(root, spec, workload, seed, fault=fault, overrides=overrides,
                 log=log)
@@ -644,7 +731,9 @@ def run_cell(root, spec, workload, seed, seconds, trace, *, t_start=None,
         trace_dir = pathlib.Path(_out_dir(root)) / f"trace-{workload}-{seed}"
         shutil.rmtree(trace_dir, ignore_errors=True)
     w = cell.window(seconds, trace_dir=trace_dir)
-    peak = peak_bytes()
+    dev = cell_devices(cell.chips)
+    peaks = peak_bytes(dev)
+    peak = max(peaks) if peaks else None
     readback = cell.readback(w["batches"]) if cell.loader else []
     counters = {"queue": dict(cell.queue.stats),
                 "pipeline": dict(cell.pipe.stats),
@@ -656,11 +745,10 @@ def run_cell(root, spec, workload, seed, seconds, trace, *, t_start=None,
     t_ref = time.perf_counter()
     checks = check(cell, w, ref, readback)
     ref_s = time.perf_counter() - t_ref
-    dev = jax.devices()
     run = make_run(cell, w, setup_s, seconds, dev[0].device_kind)
     log(window_summary(w, run))
     log({"phase": "counters", **counters, "memory_peak_bytes": peak,
-         "reference_s": ref_s})
+         "memory_peak_bytes_per_device": peaks, "reference_s": ref_s})
     kind = "per_layer" if trace else "end_to_end"
     metrics = read_metrics(root, cell_metrics(spec, workload, kind), run)
     attempted = (int(w["reader"].due.size) if w["reader"] else 0) + (
@@ -674,9 +762,9 @@ def run_cell(root, spec, workload, seed, seconds, trace, *, t_start=None,
               "metrics": metrics, "device": device}
     if trace:
         prof, tw = w["profile"], run.trace_window
-        log({"phase": "trace", "dir": str(trace_dir), "planes": [
-            [p["name"], [[ln["name"], len(ln["events"])] for ln in p["lines"]]]
-            for p in prof["planes"]]})
+        log({"phase": "trace", "dir": str(trace_dir), "planes": w["planes"],
+             "chip_planes": [p["name"]
+                             for p in trace_mod.device_planes(prof)]})
         device["busy_s"] = trace_mod.busy_seconds(prof, tw)
         device["window_s"] = (tw[1] - tw[0]) / 1e9
         result["breakdown"] = {"device_ops": trace_mod.top_ops(prof, tw),

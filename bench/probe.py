@@ -140,13 +140,11 @@ class CompileClock:
                 "compiles": self.count - mark[1]}
 
 
-def peak_bytes() -> int | None:
-    """Peak bytes in use on the fullest device, where the backend says."""
-    import jax
-
+def peak_bytes(devices) -> list:
+    """Peak bytes in use on each of ``devices``, where the backend says."""
     peaks = []
-    for d in jax.local_devices():
+    for d in devices:
         stats = d.memory_stats()
         if stats and "peak_bytes_in_use" in stats:
             peaks.append(int(stats["peak_bytes_in_use"]))
-    return max(peaks) if peaks else None
+    return peaks

@@ -1,6 +1,7 @@
 """Each cell's phases at a tiny size on the CPU, checked against the plain
 reference: build, warm, one window of traffic, every answer compared."""
 
+import json
 import os
 import shutil
 import subprocess
@@ -27,6 +28,28 @@ SMALL = {"config": {"records": 20000, "insert_pool": 8192},
          "traffic": {"open": {"rate_per_s": 300}, "warm_buckets": [512, 1024],
                      "bulk": {"batch_keys": 1024, "pool_batches": 2},
                      "load": {"batch_keys": 1024, "warm_batches": 1}}}
+
+# a range-partitioned index over four chips: the bulk mix, whose
+# 1024-key batches are large enough for the shard fan-out
+FANOUT = "ycsb_hashed_32m.fanout"
+ALL["workloads"].append({"name": FANOUT, "config": "ycsb_hashed_32m",
+                         "traffic": "bulk", "chips": 4, "why": "test"})
+
+
+def shards(overrides, k=4):
+    """``overrides`` that also build ``k`` range shards."""
+    return dict(overrides, config=dict(overrides["config"],
+                                       build={"shards": k}))
+
+
+SHARDED = shards(SMALL)
+# float32 keys alias once enough keys share a float32 value: at 2^16
+# uniform keys about 128 pairs do, so 4 batches of 2048 stored keys hit
+# some
+BIG = harness.merge(SMALL, {"config": {"records": 1 << 16},
+                            "traffic": {"warm_buckets": [2048],
+                                        "bulk": {"batch_keys": 2048,
+                                                 "pool_batches": 4}}})
 
 
 def run(workload, fault=None, overrides=SMALL, seconds=1.5):
@@ -95,3 +118,100 @@ def test_run_refuses_a_checkout_without_the_program(tmp_path):
         capture_output=True, text=True, timeout=120)
     assert p.returncode != 0
     assert p.stdout == ""
+
+
+def sharded_run(fault=None, overrides=SHARDED):
+    """A fan-out cell's result and its build and window records."""
+    recs = []
+    res = harness.run_cell(harness.ROOT, ALL, FANOUT, SEED, 1.5, 0,
+                           fault=fault, overrides=overrides,
+                           log=recs.append)
+    phase = {r["phase"]: r for r in recs if "phase" in r}
+    return res, phase["build"], phase["window"]
+
+
+def check_fanout_run(res, build, win, devices):
+    assert res["correct"], res["checks"]
+    assert res["failed"] == 0 and res["attempted"] > 0
+    assert res["device"]["count"] == devices
+    assert build["fused_impl"] == "fanout" and build["fanout_shards"] == 4
+    assert build["fanout_devices"] == devices
+    assert len(build["n_slots_per_shard"]) == 4
+    assert build["n_slots"] == sum(build["n_slots_per_shard"])
+    # every call in the window went through the fan-out
+    assert win["lookup_calls"] > 0
+    assert win["fanout_lookups"] == win["lookup_calls"]
+    assert win["compiles_in_window"] == 0
+
+
+def test_sharded_cell_serves_every_call_through_the_fanout():
+    import jax
+
+    res, build, win = sharded_run()
+    check_fanout_run(res, build, win, min(4, len(jax.devices())))
+
+
+FOUR_DEVICES = """
+import json
+import numpy as np
+from bench import harness
+from bench.tests import test_bench_cells as t
+from repro.core import Index
+res, build, win = t.sharded_run()
+# a fan-out over four devices does not fit a two-chip cell
+index = Index.build(np.arange(1.0, 4001.0), shards=4, gap_rho=0.15)
+try:
+    harness.sync_fanout(index, 2)
+    refused = False
+except harness.BenchError:
+    refused = True
+print(json.dumps([res, build, win, refused], default=harness._jsonable))
+"""
+
+
+def test_sharded_cell_exchanges_over_four_devices():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(harness.ROOT / "src"),
+                                           str(harness.ROOT)]))
+    p = subprocess.run([sys.executable, "-c", FOUR_DEVICES],
+                       cwd=harness.ROOT, env=env, capture_output=True,
+                       text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    res, build, win, refused = json.loads(p.stdout.strip().splitlines()[-1])
+    check_fanout_run(res, build, win, 4)
+    assert refused
+
+
+def test_float32_control_reads_incorrect_on_the_sharded_path():
+    res, _, _ = sharded_run(overrides=shards(BIG))
+    assert res["correct"]
+    res, _, _ = sharded_run("control_f32", overrides=shards(BIG))
+    assert not res["correct"]
+    assert res["checks"]["wrong_answers"]["value"] > 0
+
+
+def test_a_warm_up_that_misses_the_fanout_fails_set_up():
+    over = harness.merge(SHARDED, {"traffic": {"warm_buckets": [256]}})
+    with pytest.raises(harness.BenchError, match="reached the shard fan-out"):
+        sharded_run(overrides=over)
+
+
+def test_sync_fanout_builds_the_fanout_once_and_never_falls_back(
+        monkeypatch):
+    import repro.kernels.shard_fanout as sf
+    from repro.core import Index
+
+    keys = np.arange(1, 4001, dtype=np.float64) * 3
+    index = Index.build(keys, shards=4, method="pgm", gap_rho=0.15)
+    fan = harness.sync_fanout(index, 4)
+    assert isinstance(fan, sf.ShardFanout) and fan.S == 4
+    assert harness.sync_fanout(index, 4) is fan   # no shard changed
+
+    def refuse(cls, *a, **k):
+        raise sf.FanoutUnavailable("a shard exports no PLM")
+
+    monkeypatch.setattr(sf.ShardFanout, "build", classmethod(refuse))
+    other = Index.build(keys, shards=4, method="pgm", gap_rho=0.15)
+    with pytest.raises(harness.BenchError, match="cannot serve"):
+        harness.sync_fanout(other, 4)

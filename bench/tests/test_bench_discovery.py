@@ -87,6 +87,49 @@ def test_files_alone_add_a_cell_and_a_metric(tmp_path):
             / "trace_trimmed.json").is_file()
 
 
+def test_files_alone_add_a_sharded_cell_on_four_chips(tmp_path):
+    """A configuration whose build names ``shards`` and a four-chip
+    workload: a range-sharded index served by the shard fan-out."""
+    root = make_root(tmp_path)
+    cfg = json.loads((root / "bench" / "configs" / "grid_small.json")
+                     .read_text())
+    cfg["build"]["shards"] = 4
+    (root / "bench" / "configs" / "grid_sharded.json").write_text(
+        json.dumps(dict(cfg, name="grid_sharded")))
+    (root / "bench" / "traffic" / "tiny_bulk.json").write_text(json.dumps({
+        "bulk": {"batch_keys": 1024, "present_share": 0.5,
+                 "pool_batches": 2},
+        "warm_buckets": [1024], "trace_seconds": 0.3}))
+    spec = harness.load_spec(root)
+    spec["configs"].append(dict(spec["configs"][0], name="grid_sharded",
+                                file="bench/configs/grid_sharded.json"))
+    spec["workloads"] = [{"name": "grid_sharded.tiny_bulk",
+                          "config": "grid_sharded", "traffic": "tiny_bulk",
+                          "chips": 4, "why": "test"}]
+    spec["end_to_end"] = [
+        {k: v for k, v in m.items() if k != "workloads"}
+        for m in harness.load_spec()["end_to_end"]
+        if m["name"] in ("lookup_keys_per_s", "setup_s", "build_s")]
+    spec["per_layer"][0]["moves"] = "lookup_keys_per_s"
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    spec = harness.load_spec(root)
+    recs = []
+    e2e = harness.run_cell(root, spec, "grid_sharded.tiny_bulk", 5, 1.0, 0,
+                           log=recs.append)
+    assert e2e["correct"]
+    assert set(e2e["metrics"]) == {"lookup_keys_per_s", "setup_s",
+                                   "build_s"}
+    phase = {r["phase"]: r for r in recs if "phase" in r}
+    assert phase["build"]["fused_impl"] == "fanout"
+    assert phase["window"]["fanout_lookups"] == (
+        phase["window"]["lookup_calls"]) > 0
+    layer = harness.run_cell(root, spec, "grid_sharded.tiny_bulk", 6, 1.5,
+                             1, log=lambda rec: None)
+    assert layer["correct"]
+    assert layer["metrics"]["calls_per_s.tiny"]["value"] > 0
+    assert set(layer["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
 def test_a_split_metric_name_falls_back_to_its_base_reader(tmp_path):
     d = tmp_path / "bench" / "metrics"
     d.mkdir(parents=True)

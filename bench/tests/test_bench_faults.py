@@ -9,9 +9,7 @@ in the program's place).  Each must read ``correct`` false.
 
 import pytest
 
-from bench import harness
-
-from test_bench_cells import SMALL, run
+from test_bench_cells import BIG, run
 
 Y_OPEN = "ycsb_hashed_32m.c_open"
 Y_BULK = "ycsb_hashed_32m.c_bulk"
@@ -32,14 +30,7 @@ def test_fault_reads_incorrect(workload, fault):
 
 
 def test_float32_control_reads_incorrect():
-    # float32 keys alias once enough keys share a float32 value: at
-    # 2^16 uniform keys about 128 pairs do, so 4 batches of 1024 stored
-    # keys hit some
-    big = harness.merge(SMALL, {"config": {"records": 1 << 16},
-                                "traffic": {"warm_buckets": [2048],
-                                            "bulk": {"batch_keys": 2048,
-                                                     "pool_batches": 4}}})
-    assert run(Y_BULK, overrides=big)["correct"]
-    res = run(Y_BULK, "control_f32", overrides=big)
+    assert run(Y_BULK, overrides=BIG)["correct"]
+    res = run(Y_BULK, "control_f32", overrides=BIG)
     assert not res["correct"]
     assert res["checks"]["wrong_answers"]["value"] > 0

@@ -68,12 +68,49 @@ def test_trim_keeps_the_window_and_our_spans():
     assert trace.busy_seconds(t, (0.0, 50e6)) == pytest.approx(0.010)
 
 
+def two_chips():
+    """``synthetic()`` with a second chip that runs other ops, and the
+    runtime's plane that holds no chip's ops."""
+    ms = 1_000_000.0
+    p = synthetic()
+    p["planes"].append({"name": "/device:TPU:1", "lines": [
+        {"name": "XLA Ops", "events": [["scatter", 40 * ms, 30 * ms]]},
+        {"name": "XLA Modules", "events": [
+            ["jit__fused_pipeline(7)", 40 * ms, 30 * ms]]}]})
+    p["planes"].append({"name": "/device:CUSTOM:Megascale Trace",
+                        "lines": []})
+    return p
+
+
+def test_a_run_reads_only_the_planes_of_its_chips():
+    w = (0.0, 100e6)
+    both = two_chips()
+    assert trace.busy_seconds(both, w) == pytest.approx((0.025 + 0.030) / 2)
+    assert [p["name"] for p in trace.device_planes(both)] == [
+        "/device:TPU:0", "/device:TPU:1"]
+    one = trace.keep_devices(both, [0])
+    assert [p["name"] for p in trace.device_planes(one)] == ["/device:TPU:0"]
+    assert [p["name"] for p in one["planes"]] == [
+        "/host:CPU", "/device:TPU:0", "/device:CUSTOM:Megascale Trace"]
+    assert trace.busy_seconds(one, w) == pytest.approx(0.025)
+    assert trace.module_time(one, "jit__fused_pipeline", w) == (
+        pytest.approx(0.010), 1)
+    assert "scatter" not in dict(trace.top_ops(one, w))
+    assert sum(v for _, v in trace.idle_gaps(one, w)) == pytest.approx(0.075)
+    # the second chip alone, and both
+    assert trace.busy_seconds(trace.keep_devices(both, [1]), w) == (
+        pytest.approx(0.030))
+    assert trace.keep_devices(both, [0, 1]) == both
+
+
 def test_recorded_chip_traces_reduce_within_bounds():
     paths = sorted(DATA.glob("trace_*.json"))
     assert paths, "no recorded trace under bench/tests/data"
     for path in paths:
         assert path.stat().st_size < 1 << 20
         p = json.loads(path.read_text())
+        # one chip recorded: the first chip's planes are the whole trace
+        assert trace.keep_devices(p, [0]) == p
         w = trace.traced_window(p)
         busy = trace.busy_seconds(p, w)
         assert 0.0 < busy <= (w[1] - w[0]) / 1e9

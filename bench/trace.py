@@ -12,6 +12,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 
 WINDOW_SPAN = "bench.window"   # the traced window, opened by the harness
 SPAN_PREFIX = "bench."         # host spans the harness writes
@@ -38,10 +39,27 @@ def load_profile(log_dir: str) -> dict:
     return {"planes": planes}
 
 
+# a chip's plane: ``/device:TPU:0``.  Other ``/device:`` planes, such
+# as the runtime's ``Megascale Trace``, hold no chip's ops.
+CHIP_PLANE = re.compile(r"/device:(?!CPU:)[A-Za-z_]+:(\d+)")
+
+
+def _chip(plane: dict) -> int | None:
+    m = CHIP_PLANE.fullmatch(plane["name"])
+    return int(m.group(1)) if m else None
+
+
 def device_planes(profile: dict) -> list:
-    return [p for p in profile["planes"]
-            if p["name"].startswith("/device:")
-            and not p["name"].startswith("/device:CPU")]
+    """The planes of chips: one per device id."""
+    return [p for p in profile["planes"] if _chip(p) is not None]
+
+
+def keep_devices(profile: dict, ids) -> dict:
+    """``profile`` without the planes of chips outside ``ids``, so every
+    reduction reads only the chips a run used."""
+    ids = {int(i) for i in ids}
+    return {"planes": [p for p in profile["planes"]
+                       if _chip(p) is None or _chip(p) in ids]}
 
 
 def _line(plane: dict, name: str) -> list:
